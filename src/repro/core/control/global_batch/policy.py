@@ -170,8 +170,10 @@ class DynamixGlobalBatch(GlobalBatchController):
         rung toward the b_noise side shrinks |f0| by one ladder step,
         moving away grows it, and the shaped reward is the resulting
         potential difference.  That gives the policy a follow-the-GNS
-        prior out of the box; observed rewards then overwrite it through
-        the same TD updates.  Fully deterministic (no RNG draw here).
+        lean in the states the rows cover (loss slope and context features
+        0; outside them the seeded hidden layer decides, DESIGN.md §18);
+        observed rewards then overwrite it through the same TD updates.
+        Fully deterministic (no RNG draw here).
         """
         cfg = self.config
         n = len(self.rungs)
@@ -235,7 +237,9 @@ class DynamixGlobalBatch(GlobalBatchController):
                 f[3] = _clip(max(times) / mean - 1.0)
         if (cfg.time_signal == "measured" and self._xput_ewma
                 and self._last_xput):
-            f[4] = _clip(math.log2(self._last_xput / self._xput_ewma))
+            ratio = self._last_xput / self._xput_ewma
+            if ratio > 0:       # a tiny throughput over a large EWMA underflows
+                f[4] = _clip(math.log2(ratio))
         prices = ctx.get("prices")
         if prices:
             f[5] = _clip(sum(prices) / len(prices) - 1.0)

@@ -4,15 +4,25 @@ TPU-native design (DESIGN.md §14):
   * grid (batch, q_heads, num_q_blocks, num_kv_blocks) — the last axis is
     sequential on TPU, so the online-softmax running state (m, l, acc) lives
     in VMEM scratch that persists across kv-block iterations;
-  * BlockSpecs tile Q/K/V into (block_q x d) / (block_k x d) VMEM tiles with
-    head_dim zero-padded to the 128-lane register width inside this module
-    (whisper's 64 and the reduced configs' 32 no longer rely on "tiles
-    legally" — padding lanes are provably inert: zero K/V lanes add zero to
-    every dot product and the padded output/grad lanes are sliced off);
+  * head_dim is zero-padded to the 128-lane register width inside this
+    module (whisper's 64 and the reduced configs' 32: zero K/V lanes add
+    zero to every dot product and the padded output/grad lanes are sliced
+    off);
+  * lane-dense blocks: the public (B, S, H, D) tensors are viewed, for
+    free, as (B, S, H·D), and each program moves one (block, D) tile whose
+    lane block is its head — both trailing block dims are multiples of
+    (8, 128), the Mosaic tiling rule, with no head-major transpose in HBM;
+  * per-row statistics (m, l, lse, delta) are lane-replicated
+    (rows, 128) tiles, the layout of the reference TPU kernel in
+    ``jax.experimental.pallas.ops.tpu.flash_attention``; the public
+    ``lse`` residual stays (B, H, S) and is broadcast to lanes only for the
+    backward call;
   * GQA is expressed in the K/V index_map (query head h reads kv head
     h // rep) — no materialized head repetition in HBM;
   * causal + sliding-window masking is applied per tile; fully-masked tiles
-    short-circuit via @pl.when so the MXU never sees them.
+    short-circuit via @pl.when so the MXU never sees them;
+  * float32 inputs get float32 MXU passes (``precision=HIGHEST``): Mosaic's
+    default runs a float32 dot as one bfloat16 pass.
 
 Ragged batches (the bucket-ladder hot path, DESIGN.md §14): ``num_valid``
 is a *traced* int32 — one compiled executable per bucket shape serves every
@@ -39,7 +49,8 @@ them by zero and ``0 * NaN`` would poison the whole gradient.
   * ``"auto"`` — rowloop under interpret, grid otherwise.
 
 Validated on CPU with interpret=True against ref.attention_ref (forward)
-and the jnp-oracle vjp (backward, tests/test_kernel_ragged.py).
+and the jnp-oracle vjp (backward, tests/test_kernel_ragged.py); compiled
+for a described v5e chip in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -55,6 +66,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANE = 128  # TPU register lane width: last block dim should be a multiple
+_TRANS_B = (((1,), (1,)), ((), ()))  # a @ b.T without materializing b.T
+
+
+def _precision(dtype):
+    """MXU precision for kernel inputs of ``dtype``: full float32 passes
+    for float32 (the default would round operands to bfloat16); narrower
+    inputs lose nothing at the default."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -68,6 +87,26 @@ def _pad_lanes(x):
     if dp == d:
         return x
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, dp - d)])
+
+
+def _fold(x):
+    """(B, S, H, D) -> (B, S, H·D): head h becomes lane block h (free)."""
+    b, s, h, d = x.shape
+    return x.reshape(b, s, h * d)
+
+
+def _lanes(x, n: int):
+    """Lane-replicated (rows, LANE) statistic -> (rows, n)."""
+    if n <= LANE:
+        return x[:, :n]
+    if n % LANE:
+        raise ValueError(f"block width {n} must be <= {LANE} or a multiple")
+    return jnp.tile(x, (1, n // LANE))
+
+
+def _row_stat(x):
+    """(B, H, S) per-row statistic -> lane-replicated (B, H, S, LANE)."""
+    return jnp.broadcast_to(x[..., None], x.shape + (LANE,))
 
 
 def _resolve_impl(ragged_impl: str, interpret: bool) -> str:
@@ -118,21 +157,77 @@ def _tile_mask(iq, ik, *, block_q, block_k, seq_q, seq_k, causal, window):
     return mask
 
 
+def _gate(valid, visible):
+    """Combine the ragged row guard with the tile-visibility predicate."""
+    if valid is True:
+        return visible
+    return valid if visible is True else jnp.logical_and(valid, visible)
+
+
+def _bsel(b_, nvr):
+    """Clamp a padded row's batch coordinate to 0 (no out-of-range DMA)."""
+    return b_ if nvr is None else jnp.where(b_ < nvr[0], b_, 0)
+
+
+def _pallas(kernel, nv, *, grid, in_specs, out_specs, out_shape, scratch,
+            interpret, args):
+    """One pallas_call; ragged calls scalar-prefetch ``nv`` and pass it to
+    every index map as its last argument.  Index maps are written as
+    ``fn(b, h, i2, i3, nvr=None)``."""
+    if nv is None:
+        return pl.pallas_call(
+            kernel, grid=grid,
+            in_specs=[pl.BlockSpec(blk, fn) for blk, fn in in_specs],
+            out_specs=[pl.BlockSpec(blk, fn) for blk, fn in out_specs],
+            out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret)(*args)
+
+    def spec(blk, fn):
+        return pl.BlockSpec(blk, lambda *ix: fn(*ix[:-1], nvr=ix[-1]))
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[spec(blk, fn) for blk, fn in in_specs],
+            out_specs=[spec(blk, fn) for blk, fn in out_specs],
+            scratch_shapes=scratch),
+        out_shape=out_shape, interpret=interpret)(nv, *args)
+
+
+def _zero_padded_rows(nv, *xs):
+    """Rows the dynamic grid never launched hold uninitialized memory."""
+    if nv is None:
+        return xs
+    return tuple(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (x.shape[0],) + (1,) * (x.ndim - 1),
+                                 0) < nv[0], x, 0.0).astype(x.dtype)
+        for x in xs)
+
+
+def _grid_rows(nv, b):
+    """(batch-grid extent, prefetch operand): the extent is dynamic when
+    ragged, so programs for padded rows are never launched."""
+    if nv is None:
+        return b, None
+    nv = jnp.asarray(nv, jnp.int32).reshape(-1)[:1]
+    return jnp.clip(nv[0], 0, b), nv
+
+
 # ----------------------------------------------------------------- forward
 
 
 def _fwd_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
                 softcap, sm_scale, ragged):
     if ragged:
-        nv_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref \
-            = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
+        nv_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     bi = pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
     valid = (bi < nv_ref[0]) if ragged else True
+    d = acc_ref.shape[-1]
 
     @pl.when(ik == 0)
     def init():
@@ -142,42 +237,42 @@ def _fwd_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
 
     geom = dict(block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
                 causal=causal, window=window)
-    visible = _tile_visible(iq, ik, **geom)
-    gate = visible if valid is True else (
-        jnp.logical_and(valid, visible) if visible is not True else valid)
+
+    prec = _precision(q_ref.dtype)
 
     def compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        q = q_ref[...].astype(jnp.float32)                  # (bq, d)
+        k = k_ref[...].astype(jnp.float32)                  # (bk, d)
+        v = v_ref[...].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, _TRANS_B, precision=prec,
+                                preferred_element_type=jnp.float32) * sm_scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
         mask = _tile_mask(iq, ik, **geom)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
+        m_prev = m_ref[...]                                 # (bq, LANE)
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        p = jnp.exp(s - _lanes(m_cur, block_k))
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(
+            p, v, precision=prec, preferred_element_type=jnp.float32)
         m_ref[...] = m_cur
 
-    _guarded(gate, compute)
+    _guarded(_gate(valid, _tile_visible(iq, ik, **geom)), compute)
 
     @pl.when(ik == nk - 1)
     def finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-20)
-        out = acc_ref[...] / l_safe[:, None]
+        out = acc_ref[...] / _lanes(l_safe, d)
         lse = m_ref[...] + jnp.log(l_safe)
         if ragged:  # padded rows must be finite zeros, never garbage
             out = jnp.where(valid, out, 0.0)
             lse = jnp.where(valid, lse, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
-        lse_ref[0, 0, :] = lse
+        o_ref[...] = out.astype(o_ref.dtype)
+        lse_ref[...] = lse
 
 
 def _fwd_call(q, k, v, nv, *, causal, window, softcap, sm_scale,
@@ -186,76 +281,34 @@ def _fwd_call(q, k, v, nv, *, causal, window, softcap, sm_scale,
     b, s, h, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
-    ragged = nv is not None
+    nb, nv = _grid_rows(nv, b)
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, seq_q=s, seq_k=t,
         causal=causal, window=window, softcap=softcap, sm_scale=sm_scale,
-        ragged=ragged)
-    out_shape = [jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
-                 jax.ShapeDtypeStruct((b, h, s), jnp.float32)]
-    scratch = [pltpu.VMEM((block_q,), jnp.float32),
-               pltpu.VMEM((block_q,), jnp.float32),
-               pltpu.VMEM((block_q, d), jnp.float32)]
+        ragged=nv is not None)
 
-    if not ragged:
-        grid = (b, h, s // block_q, t // block_k)
-        out, lse = pl.pallas_call(
-            kernel, grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, 1, d),
-                             lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda b_, h_, iq, ik: (b_, ik, h_ // rep, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda b_, h_, iq, ik: (b_, ik, h_ // rep, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, 1, d),
-                             lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-                pl.BlockSpec((1, 1, block_q),
-                             lambda b_, h_, iq, ik: (b_, h_, iq)),
-            ],
-            out_shape=out_shape, scratch_shapes=scratch,
-            interpret=interpret)(q, k, v)
-        return out, lse
+    def q_at(b_, h_, iq, ik, nvr=None):
+        return (_bsel(b_, nvr), iq, h_)
 
-    # ragged: dynamic batch-grid extent + scalar-prefetched guard; index
-    # maps clamp the batch coordinate so guarded programs never prefetch
-    # out-of-range blocks (DESIGN.md §14)
-    nv = jnp.asarray(nv, jnp.int32).reshape(-1)[:1]
-    nb = jnp.clip(nv[0], 0, b)
-    grid = (nb, h, s // block_q, t // block_k)
+    def kv_at(b_, h_, iq, ik, nvr=None):
+        return (_bsel(b_, nvr), ik, h_ // rep)
 
-    def bsel(b_, nvr):
-        return jnp.where(b_ < nvr[0], b_, 0)
-
-    out, lse = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, 1, d),
-                             lambda b_, h_, iq, ik, nvr:
-                             (bsel(b_, nvr), iq, h_, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda b_, h_, iq, ik, nvr:
-                             (bsel(b_, nvr), ik, h_ // rep, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda b_, h_, iq, ik, nvr:
-                             (bsel(b_, nvr), ik, h_ // rep, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, 1, d),
-                             lambda b_, h_, iq, ik, nvr: (b_, iq, h_, 0)),
-                pl.BlockSpec((1, 1, block_q),
-                             lambda b_, h_, iq, ik, nvr: (b_, h_, iq)),
-            ],
-            scratch_shapes=scratch),
-        out_shape=out_shape, interpret=interpret)(nv, q, k, v)
-    # rows the dynamic grid never launched hold uninitialized memory
-    rows = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, 1), 0)
-    out = jnp.where(rows < nv[0], out, 0.0).astype(out.dtype)
-    lse = jnp.where(rows[..., 0] < nv[0], lse, 0.0)
+    out, lse = _pallas(
+        kernel, nv, grid=(nb, h, s // block_q, t // block_k),
+        in_specs=[((None, block_q, d), q_at),
+                  ((None, block_k, d), kv_at),
+                  ((None, block_k, d), kv_at)],
+        out_specs=[((None, block_q, d),
+                    lambda b_, h_, iq, ik, nvr=None: (b_, iq, h_)),
+                   ((None, None, block_q, LANE),
+                    lambda b_, h_, iq, ik, nvr=None: (b_, h_, iq, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, s, LANE), jnp.float32)],
+        scratch=[pltpu.VMEM((block_q, LANE), jnp.float32),
+                 pltpu.VMEM((block_q, LANE), jnp.float32),
+                 pltpu.VMEM((block_q, d), jnp.float32)],
+        interpret=interpret, args=(_fold(q), _fold(k), _fold(v)))
+    out, lse = _zero_padded_rows(nv, out.reshape(b, s, h, d), lse[..., 0])
     return out, lse
 
 
@@ -333,24 +386,26 @@ def flash_attention(q, k, v, *, num_valid=None, ragged_impl: str = "auto",
 
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, iq, ik, *,
               softcap, sm_scale, geom):
-    """Shared per-tile backward math -> (p, ds) both (bq, bk) f32."""
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
-    lse = lse_ref[0, 0, :]
-    delta = dl_ref[0, 0, :]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+    """Shared per-tile backward math -> (q, k, do, p, ds); p, ds (bq, bk)."""
+    bk = geom["block_k"]
+    prec = _precision(q_ref.dtype)
+    q = q_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    do = do_ref[...].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, _TRANS_B, precision=prec,
+                            preferred_element_type=jnp.float32) * sm_scale
     if softcap is not None:
         s_soft = softcap * jnp.tanh(s / softcap)
     else:
         s_soft = s
-    p = jnp.exp(s_soft - lse[:, None])
+    p = jnp.exp(s_soft - _lanes(lse_ref[...], bk))
     mask = _tile_mask(iq, ik, **geom)
     if mask is not None:
         p = jnp.where(mask, p, 0.0)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    dp = jax.lax.dot_general(do, v, _TRANS_B, precision=prec,
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - _lanes(dl_ref[...], bk))
     if softcap is not None:  # d tanh: 1 - (s_soft / cap)^2
         ds = ds * (1.0 - jnp.square(s_soft / softcap))
     return q, k, do, p, ds
@@ -359,10 +414,8 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, iq, ik, *,
 def _dq_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
                softcap, sm_scale, ragged):
     if ragged:
-        nv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, \
-            dq_acc = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dq_acc = refs
+        nv_ref, *refs = refs
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dq_acc = refs
     bi = pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -375,35 +428,30 @@ def _dq_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
 
     geom = dict(block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
                 causal=causal, window=window)
-    visible = _tile_visible(iq, ik, **geom)
-    gate = visible if valid is True else (
-        jnp.logical_and(valid, visible) if visible is not True else valid)
 
     def compute():
         _, k, _, _, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    dl_ref, iq, ik, softcap=softcap,
                                    sm_scale=sm_scale, geom=geom)
-        dq_acc[...] += jnp.dot(ds, k,
+        dq_acc[...] += jnp.dot(ds, k, precision=_precision(q_ref.dtype),
                                preferred_element_type=jnp.float32) * sm_scale
 
-    _guarded(gate, compute)
+    _guarded(_gate(valid, _tile_visible(iq, ik, **geom)), compute)
 
     @pl.when(ik == nk - 1)
     def finalize():
         dq = dq_acc[...]
         if ragged:
             dq = jnp.where(valid, dq, 0.0)
-        dq_ref[0, :, 0, :] = dq.astype(dq_ref.dtype)
+        dq_ref[...] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
                 softcap, sm_scale, ragged):
     if ragged:
-        nv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, \
-            dv_ref, dk_acc, dv_acc = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref, \
-            dk_acc, dv_acc = refs
+        nv_ref, *refs = refs
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref, \
+        dk_acc, dv_acc = refs
     bi = pl.program_id(0)
     ik = pl.program_id(2)   # kv block: this program's output tile
     iq = pl.program_id(3)   # q block: the sequential accumulation axis
@@ -417,19 +465,18 @@ def _dkv_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
 
     geom = dict(block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k,
                 causal=causal, window=window)
-    visible = _tile_visible(iq, ik, **geom)
-    gate = visible if valid is True else (
-        jnp.logical_and(valid, visible) if visible is not True else valid)
 
     def compute():
         q, _, do, p, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                     dl_ref, iq, ik, softcap=softcap,
                                     sm_scale=sm_scale, geom=geom)
-        dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dk_acc[...] += jnp.dot(ds.T, q,
+        prec = _precision(q_ref.dtype)
+        dv_acc[...] += jnp.dot(p.T, do, precision=prec,
+                               preferred_element_type=jnp.float32)
+        dk_acc[...] += jnp.dot(ds.T, q, precision=prec,
                                preferred_element_type=jnp.float32) * sm_scale
 
-    _guarded(gate, compute)
+    _guarded(_gate(valid, _tile_visible(iq, ik, **geom)), compute)
 
     @pl.when(iq == nq - 1)
     def finalize():
@@ -437,8 +484,8 @@ def _dkv_kernel(*refs, block_q, block_k, seq_q, seq_k, causal, window,
         if ragged:
             dk = jnp.where(valid, dk, 0.0)
             dv = jnp.where(valid, dv, 0.0)
-        dk_ref[0, :, 0, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv.astype(dv_ref.dtype)
+        dk_ref[...] = dk.astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
 def _bwd_call(q, k, v, do, lse, delta, nv, *, causal, window, softcap,
@@ -450,118 +497,68 @@ def _bwd_call(q, k, v, do, lse, delta, nv, *, causal, window, softcap,
     b, s, h, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
-    ragged = nv is not None
+    nb, nv = _grid_rows(nv, b)
     kw = dict(block_q=block_q, block_k=block_k, seq_q=s, seq_k=t,
               causal=causal, window=window, softcap=softcap,
-              sm_scale=sm_scale, ragged=ragged)
+              sm_scale=sm_scale, ragged=nv is not None)
     nq, nk = s // block_q, t // block_k
-
-    if ragged:
-        nv = jnp.asarray(nv, jnp.int32).reshape(-1)[:1]
-        nb = jnp.clip(nv[0], 0, b)
-    else:
-        nb = b
-
-    def spec(block, fn):
-        if not ragged:
-            return pl.BlockSpec(block, fn)
-        return pl.BlockSpec(
-            block, lambda *ix: fn(*ix[:-1], nvr=ix[-1]))
-
-    def bsel(b_, nvr):
-        return b_ if nvr is None else jnp.where(b_ < nvr[0], b_, 0)
+    args = (_fold(q), _fold(k), _fold(v), _fold(do), _row_stat(lse),
+            _row_stat(delta))
+    call = functools.partial(_pallas, nv=nv, interpret=interpret, args=args)
 
     # ---- dq: grid (B, H, nq, nk), accumulate over the trailing k axis ----
     def q_at_2(b_, h_, i2, i3, nvr=None):
-        return (bsel(b_, nvr), i2, h_, 0)
+        return (_bsel(b_, nvr), i2, h_)
 
     def kv_at_3(b_, h_, i2, i3, nvr=None):
-        return (bsel(b_, nvr), i3, h_ // rep, 0)
+        return (_bsel(b_, nvr), i3, h_ // rep)
 
     def row_at_2(b_, h_, i2, i3, nvr=None):
-        return (bsel(b_, nvr), h_, i2)
+        return (_bsel(b_, nvr), h_, i2, 0)
 
-    def out_q_at_2(b_, h_, i2, i3, nvr=None):
-        return (b_, i2, h_, 0)
-
-    dq_in_specs = [
-        spec((1, block_q, 1, d), q_at_2),    # q
-        spec((1, block_k, 1, d), kv_at_3),   # k
-        spec((1, block_k, 1, d), kv_at_3),   # v
-        spec((1, block_q, 1, d), q_at_2),    # do
-        spec((1, 1, block_q), row_at_2),     # lse
-        spec((1, 1, block_q), row_at_2),     # delta
-    ]
-    dq_args = dict(
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
-        interpret=interpret)
-    dq_scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
-    dq_kernel = functools.partial(_dq_kernel, **kw)
-    if ragged:
-        dq = pl.pallas_call(
-            dq_kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(nb, h, nq, nk),
-                in_specs=dq_in_specs,
-                out_specs=spec((1, block_q, 1, d), out_q_at_2),
-                scratch_shapes=dq_scratch),
-            **dq_args)(nv, q, k, v, do, lse, delta)
-    else:
-        dq = pl.pallas_call(
-            dq_kernel, grid=(nb, h, nq, nk), in_specs=dq_in_specs,
-            out_specs=spec((1, block_q, 1, d), out_q_at_2),
-            scratch_shapes=dq_scratch, **dq_args)(q, k, v, do, lse, delta)
+    (dq,) = call(
+        functools.partial(_dq_kernel, **kw), grid=(nb, h, nq, nk),
+        in_specs=[((None, block_q, d), q_at_2),               # q
+                  ((None, block_k, d), kv_at_3),              # k
+                  ((None, block_k, d), kv_at_3),              # v
+                  ((None, block_q, d), q_at_2),               # do
+                  ((None, None, block_q, LANE), row_at_2),    # lse
+                  ((None, None, block_q, LANE), row_at_2)],   # delta
+        out_specs=[((None, block_q, d),
+                    lambda b_, h_, i2, i3, nvr=None: (b_, i2, h_))],
+        out_shape=[jax.ShapeDtypeStruct((b, s, h * d), q.dtype)],
+        scratch=[pltpu.VMEM((block_q, d), jnp.float32)])
 
     # ---- dkv: grid (B, H, nk, nq), accumulate over the trailing q axis ----
     def q_at_3(b_, h_, i2, i3, nvr=None):
-        return (bsel(b_, nvr), i3, h_, 0)
+        return (_bsel(b_, nvr), i3, h_)
 
     def kv_at_2(b_, h_, i2, i3, nvr=None):
-        return (bsel(b_, nvr), i2, h_ // rep, 0)
+        return (_bsel(b_, nvr), i2, h_ // rep)
 
     def row_at_3(b_, h_, i2, i3, nvr=None):
-        return (bsel(b_, nvr), h_, i3)
+        return (_bsel(b_, nvr), h_, i3, 0)
 
     def out_kv_at_2(b_, h_, i2, i3, nvr=None):
-        return (b_, i2, h_, 0)
+        return (b_, i2, h_)
 
-    dkv_in_specs = [
-        spec((1, block_q, 1, d), q_at_3),    # q
-        spec((1, block_k, 1, d), kv_at_2),   # k
-        spec((1, block_k, 1, d), kv_at_2),   # v
-        spec((1, block_q, 1, d), q_at_3),    # do
-        spec((1, 1, block_q), row_at_3),     # lse
-        spec((1, 1, block_q), row_at_3),     # delta
-    ]
-    dkv_out_specs = [spec((1, block_k, 1, d), out_kv_at_2),
-                     spec((1, block_k, 1, d), out_kv_at_2)]
-    dkv_args = dict(
-        out_shape=[jax.ShapeDtypeStruct((b, t, h, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, t, h, d), v.dtype)],
-        interpret=interpret)
-    dkv_scratch = [pltpu.VMEM((block_k, d), jnp.float32),
-                   pltpu.VMEM((block_k, d), jnp.float32)]
-    dkv_kernel = functools.partial(_dkv_kernel, **kw)
-    if ragged:
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(nb, h, nk, nq),
-                in_specs=dkv_in_specs, out_specs=dkv_out_specs,
-                scratch_shapes=dkv_scratch),
-            **dkv_args)(nv, q, k, v, do, lse, delta)
-    else:
-        dk, dv = pl.pallas_call(
-            dkv_kernel, grid=(nb, h, nk, nq), in_specs=dkv_in_specs,
-            out_specs=dkv_out_specs, scratch_shapes=dkv_scratch,
-            **dkv_args)(q, k, v, do, lse, delta)
+    dk, dv = call(
+        functools.partial(_dkv_kernel, **kw), grid=(nb, h, nk, nq),
+        in_specs=[((None, block_q, d), q_at_3),               # q
+                  ((None, block_k, d), kv_at_2),              # k
+                  ((None, block_k, d), kv_at_2),              # v
+                  ((None, block_q, d), q_at_3),               # do
+                  ((None, None, block_q, LANE), row_at_3),    # lse
+                  ((None, None, block_q, LANE), row_at_3)],   # delta
+        out_specs=[((None, block_k, d), out_kv_at_2),
+                   ((None, block_k, d), out_kv_at_2)],
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * d), k.dtype),
+                   jax.ShapeDtypeStruct((b, t, h * d), v.dtype)],
+        scratch=[pltpu.VMEM((block_k, d), jnp.float32),
+                 pltpu.VMEM((block_k, d), jnp.float32)])
 
-    if ragged:  # rows the dynamic grid never launched
-        rows = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, 1), 0)
-        dq = jnp.where(rows < nv[0], dq, 0.0).astype(dq.dtype)
-        dk = jnp.where(rows < nv[0], dk, 0.0).astype(dk.dtype)
-        dv = jnp.where(rows < nv[0], dv, 0.0).astype(dv.dtype)
-    return dq, dk, dv
+    return _zero_padded_rows(nv, dq.reshape(b, s, h, d),
+                             dk.reshape(b, t, h, d), dv.reshape(b, t, h, d))
 
 
 def _bwd_rowloop(q, k, v, do, lse, delta, nv, **kw):

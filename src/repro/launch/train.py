@@ -32,6 +32,7 @@ from repro.configs import get_config, list_architectures
 from repro.core import ControllerConfig, GLOBAL_BATCH_KINDS, GlobalBatchConfig
 from repro.data import DataPipeline
 from repro.het import traces
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import reduced
 from repro.optim import adam, batch_coupled
 
@@ -123,6 +124,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def normalize(mesh_info, batch: int):
     """Drop the dp axes when the batch doesn't divide them (e.g. batch 1
@@ -88,7 +86,7 @@ def mla_decode_attention(q_eff, q_rope, c_new, kr_new, cache_c, cache_kr,
         return out_lat.astype(qe_b.dtype), cc, ckr
 
     dp = tuple(dp_axes) if dp_axes else None
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None, tp_axis), P(dp, None, None, tp_axis),
                   P(dp, None, tp_axis), P(dp, None, None, tp_axis),
@@ -135,7 +133,7 @@ def decode_attention(q, k_new, v_new, cache_k, cache_v, idx, *, mesh_info,
     dp = tuple(dp_axes) if dp_axes else None
     qspec = P(dp, None, None, tp_axis)
     cspec = P(dp, None, None, tp_axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, cspec, cspec, cspec, cspec, P(dp)),
         out_specs=(qspec, cspec, cspec),

@@ -77,7 +77,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (
     SlicePlan,
     bucket_up,
@@ -296,6 +295,9 @@ class MeshTrainer(OuterBatchMixin):
         # (dispatch_ts, completion_ts) per worker for the last concurrent
         # BSP round (concurrency diagnostics; None until one ran)
         self.last_round_stamps: Optional[list[tuple[float, float]]] = None
+        # per worker, the distinct device sets its outputs of that round
+        # live on (placement diagnostics: one set, its own slice's devices)
+        self.last_round_devices: Optional[list[set[frozenset]]] = None
         self.worker_buckets: list[set[int]] = [set() for _ in range(self.k)]
         # --- slice placement + per-worker compiled calls ---
         # devices with the data axes flattened to the front: row i is the
@@ -366,22 +368,18 @@ class MeshTrainer(OuterBatchMixin):
             return (g_mean, jax.lax.psum(loss_sum, daxes),
                     jax.lax.psum(w_sum, daxes))
 
-        sharded = shard_map(
-            worker_fn, mesh_obj,
+        sharded = jax.shard_map(
+            worker_fn, mesh=mesh_obj,
             in_specs=(P(), P(daxes), P(daxes)),
             out_specs=(P(), P(), P(), P()) if need_stats else (P(), P(), P()),
             # grads ARE replicated over non-data axes (identical inputs and
-            # deterministic compute per slice); 0.4's static rep-checker
-            # cannot always prove it, so the check is off
+            # deterministic compute per slice), but the Pallas kernel's
+            # outputs carry no varying-axis type, so the check is off
             check_vma=False)
-        # the stacked data/mask buffers are never reused after the call
-        # (the solo rerun re-transfers from host), so donate them where the
-        # backend can actually alias; on CPU donation is a warning no-op
-        donate = () if jax.default_backend() == "cpu" else (1, 2)
         return _WorkerExec(
             mesh=mesh_obj, daxes=daxes, quantum=quantum,
             bucket_base=bucket_base,
-            gradfn=jax.jit(sharded, donate_argnums=donate),
+            gradfn=jax.jit(sharded),
             slice=slice_,
             data_sharding=NamedSharding(mesh_obj, P(daxes)),
             params_sharding=NamedSharding(mesh_obj, P()),
@@ -460,6 +458,24 @@ class MeshTrainer(OuterBatchMixin):
         rec = self._exec[worker]
         return bucket_up(batch, base=rec.bucket_base, growth=self.growth,
                          quantum=rec.quantum)
+
+    def compiled_step(self, worker: int, batch):
+        """``worker``'s compiled gradient step for ``batch`` (a pytree of
+        arrays or ``jax.ShapeDtypeStruct``; leading dim a bucket of its
+        ladder).  The same program its rounds run, so a warm compile cache
+        serves it; for inspecting the compiled text or memory."""
+        rec = self._exec[worker]
+
+        def spec(x, sharding):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        params = jax.tree.map(lambda x: spec(x, rec.params_sharding),
+                              self.params)
+        data = jax.tree.map(lambda x: spec(x, rec.data_sharding), batch)
+        rows = jax.tree.leaves(batch)[0].shape[0]
+        mask = jax.ShapeDtypeStruct((rows,), jnp.float32,
+                                    sharding=rec.data_sharding)
+        return rec.gradfn.lower(params, data, mask).compile()
 
     def bucket(self, batch: int) -> int:
         """Full-axis ladder rung (the fallback path's shape for ``batch``)."""
@@ -620,6 +636,9 @@ class MeshTrainer(OuterBatchMixin):
         # once (benchmarks/backend_bench.py asserts this)
         self.last_round_stamps = [(d.t0, done)
                                   for d, done in zip(dispatches, stamps)]
+        self.last_round_devices = [
+            {frozenset(x.sharding.device_set) for x in jax.tree.leaves(d.out)}
+            for d in dispatches]
         grads, losses, weights, raw_times, sqnorms = [], 0.0, 0.0, [], []
         for d, done in zip(dispatches, stamps):
             dt = done - d.t0

@@ -76,9 +76,12 @@ def outer_cfg(kind: str) -> GlobalBatchConfig:
     if kind == "bandit":
         return GlobalBatchConfig(kind="bandit", bandit_window=3,
                                  time_signal="steps", **common)
+    # seed 3: at seed 0 the policy holds on every decision of this run,
+    # which would leave the dynamix legs vacuous (no resize to compare);
+    # which seeds move is an open debt (DESIGN.md §18, ROADMAP.md §3)
     return GlobalBatchConfig(kind="dynamix", bandit_window=3,
                              gns_min_samples=2, time_signal="steps",
-                             **common)
+                             **{**common, "seed": 3})
 
 
 def _even_split(total: int, k: int) -> list:

@@ -141,6 +141,12 @@ def main() -> int:
         assert abs(rec.iteration_time - max(rec.worker_times)) < 1e-12, \
             "BSP round must cost max-of-workers, not sum"
     assert out["final_loss"] < out["history"][0].loss
+    # each worker's outputs of the last round live on its own slice's rows
+    rows = np.asarray(mesh.devices)
+    for w, (start, length) in enumerate(plan.slices):
+        want = frozenset(rows[start:start + length].flat)
+        assert trainer.last_round_devices[w] == {want}, \
+            (w, trainer.last_round_devices[w], want)
 
     # ---- checkpoint/resume bit-equivalence on the debug mesh ----
     path = os.path.join(tempfile.mkdtemp(), "ckpt")

@@ -14,6 +14,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import get_config  # noqa: E402
+from repro.launch.mesh import make_debug_mesh  # noqa: E402
 from repro.models import apply_lm, init_caches, init_lm, reduced  # noqa: E402
 from repro.models import shard_hooks  # noqa: E402
 
@@ -42,7 +43,7 @@ def run(arch: str) -> int:
 
     plain = decode_all()
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_debug_mesh(4)
     shard_hooks.set_rules({"decode_attn": (mesh, ("data",), "model")})
     try:
         with mesh:

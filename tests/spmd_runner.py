@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.configs import get_config  # noqa: E402
 from repro.launch import sharding as SH  # noqa: E402
 from repro.launch import steps as ST  # noqa: E402
+from repro.launch.mesh import make_debug_mesh  # noqa: E402
 from repro.models import init_lm, reduced  # noqa: E402
 from repro.models import shard_hooks  # noqa: E402
 from repro.optim import adam  # noqa: E402
@@ -30,7 +31,7 @@ def main(arch: str) -> int:
                         lru_width=128)
     if cfg.attention == "mla":
         cfg = cfg.with_(num_heads=4, head_dim=0)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_debug_mesh(8)
     shard_hooks.set_rules({
         "logits": NamedSharding(mesh, P("data", None, "model")),
         "activations": NamedSharding(mesh, P("data", None, None)),

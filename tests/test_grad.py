@@ -51,8 +51,6 @@ def test_weighted_psum_equals_masked_mean():
     """spmd-mode combine: weighted psum over a 1-axis mesh shard_map."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
-
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs, ("data",))
     rng = np.random.default_rng(1)
@@ -63,8 +61,8 @@ def test_weighted_psum_equals_masked_mean():
         local = (g * w[:, None]).sum(0)
         return weighted_psum(local, w.sum(), "data")
 
-    out = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                    out_specs=P())(grads, weights)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                        out_specs=P())(grads, weights)
     expect = (np.asarray(grads) * np.asarray(weights)[:, None]).sum(0) / 3.0
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-6)
 

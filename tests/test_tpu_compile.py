@@ -1,0 +1,74 @@
+"""Compile the main-path Pallas kernel for a described TPU v5e chip.
+
+Interpret mode runs a kernel's body on the CPU and never asks the chip's
+compiler (Mosaic) whether its blocks tile; these tests do, with no chip
+attached.  The flash-attention forward on the ragged grid, and forward
+plus backward through ``ops.attention``, at Yi-9B's head geometry and the
+smoke run's shapes (configs/yi_9b.py, chip_smoke.py): a 4-row bucket of
+4096 tokens, 32 query heads and 4 KV heads of 128.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports every test file.  The persistent compilation cache is off around
+these compiles, since an entry written for a described chip cannot be read
+back without one.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention.ops import attention
+
+B, S, H, HKV, D = 4, 4096, 32, 4, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+def _inputs(sharding, dtype):
+    q = jax.ShapeDtypeStruct((B, S, H, D), dtype, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, S, HKV, D), dtype, sharding=sharding)
+    nv = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
+    return q, kv, kv, nv
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ragged_forward_compiles(one_chip, dtype):
+    def fwd(q, k, v, nv):
+        return flash_attention(q, k, v, num_valid=nv, ragged_impl="grid")
+
+    compiled = jax.jit(fwd).lower(*_inputs(one_chip, dtype)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_forward_and_backward_compile(one_chip, dtype):
+    def grads(q, k, v, nv):
+        def loss(q_, k_, v_):
+            out = attention(q_, k_, v_, num_valid=nv)
+            return out.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(*_inputs(one_chip, dtype)).compile()
+    # the forward, the dq kernel and the dk/dv kernel
+    assert compiled.as_text().count("tpu_custom_call") == 3

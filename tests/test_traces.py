@@ -88,7 +88,9 @@ class TestBoundaries:
         assert tr(restore) == 1.0       # t == restore: already back
         assert tr(at + dur / 2) == level
         if at > 0:
-            assert tr(at * (1 - 1e-9)) == 1.0
+            # the float just before `at`: at * (1 - 1e-9) rounds back to
+            # `at` when `at` is subnormal
+            assert tr(math.nextafter(at, 0.0)) == 1.0
 
     def test_preemption_without_restore_never_returns(self):
         tr = traces.preemption(3.0, level=0.5)
