@@ -1,0 +1,8 @@
+"""The flash-attention kernels' device time over the device's busy time
+in the traced window, summed over the chips."""
+
+
+def read(run):
+    if run.trace is None or not run.trace.kernel_s:
+        return None
+    return 100.0 * run.trace.kernel_s / run.trace.busy_total_s()
