@@ -59,6 +59,7 @@ from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_data_mesh  # noqa: E402
 from repro.models import lm_loss  # noqa: E402
 from repro.optim import adam  # noqa: E402
+from repro.train import spans  # noqa: E402
 
 ARCH = "yi-9b"
 VOCAB_SHARE = 8          # the vocabulary is split over 8 chips
@@ -118,21 +119,11 @@ def check(cond: bool, what: str) -> None:
 # --------------------------------------------------------------- measuring
 
 
-class CompileMeter:
-    """Counts backend compiles (persistent-cache loads included) and their
-    seconds from JAX's monitoring events, from construction on."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.count = 0
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == self.EVENT:
-            self.count += 1
-            self.seconds += duration
+def compiles_since(start: tuple[int, float]) -> tuple[int, float]:
+    """Backend compiles (persistent-cache loads included) and their seconds
+    since ``start``, an earlier ``spans.compiles()``."""
+    n, s = spans.compiles()
+    return n - start[0], s - start[1]
 
 
 class RoundLog(Hook):
@@ -277,11 +268,12 @@ def print_cuts(cfg, seed: int) -> None:
 def one_chip(cfg, *, seq_len: int, seed: int) -> None:
     """3 time-multiplexed workers on one chip, dynamic batching,
     heterogeneity from the cluster spec's declared speeds."""
-    meter = CompileMeter()
+    start = spans.compiles()
     trainer, out, log = train(cfg, seq_len=seq_len, seed=seed,
                               backend=MeshBackend(dilation="from-spec"))
+    compiles, compile_s = compiles_since(start)
     emit("train", rounds=out["steps"], wall_seconds=out["wall_time"],
-         compiles=meter.count, compile_seconds=meter.seconds,
+         compiles=compiles, compile_seconds=compile_s,
          worker_traces=trainer.accum_traces,
          buckets=[sorted(b) for b in trainer.worker_buckets],
          peak_bytes_in_use=peak_bytes(jax.devices()[:1]))
@@ -324,15 +316,16 @@ def four_chip(cfg, *, seq_len: int, seed: int) -> None:
     runs = {}
     for concurrent in (True, False):
         leg = "concurrent" if concurrent else "sequential"
-        meter = CompileMeter()
+        start = spans.compiles()
         trainer, out, log = train(
             cfg, seq_len=seq_len, seed=seed,
             backend=MeshBackend(mesh=mesh, concurrent=concurrent),
             b0=4, microbatch=4, batching="uniform")
+        compiles, compile_s = compiles_since(start)
         check(bool(np.all(np.isfinite(log.losses))),
               f"{leg}: non-finite loss in {log.losses}")
         emit(leg, rounds=out["steps"], wall_seconds=out["wall_time"],
-             compiles=meter.count, compile_seconds=meter.seconds,
+             compiles=compiles, compile_seconds=compile_s,
              slices=(trainer.slice_plan.slices
                      if trainer.slice_plan is not None else None),
              last_round_stamps=trainer.last_round_stamps,

@@ -122,6 +122,10 @@ class StepRecord:
     adjusted: bool
     straggler_waste: float
     worker_times: Optional[list] = None   # per-worker times (BSP rounds)
+    # mesh BSP rounds: host seconds per span name (repro.train.spans); the
+    # main thread's spans tile the round, ``transfer`` runs beside them on
+    # a thread of its own and joins the dict when the moved gradients land
+    phases: Optional[dict] = None
 
 
 class OuterBatchMixin:
@@ -192,8 +196,11 @@ class OuterBatchMixin:
             # `update` again would silently reuse the trace that baked the
             # old scale instead of re-reading sched.scale
             upd = self.optimizer.update
-            self._opt_jit_cache[key] = jax.jit(
-                lambda p, g, s, t, _u=upd: _u(p, g, s, t))
+
+            def update(p, g, s, t):     # its program is jit_update
+                return upd(p, g, s, t)
+
+            self._opt_jit_cache[key] = jax.jit(update)
         self._opt_update = self._opt_jit_cache[key]
 
     def _worker_prices(self) -> Optional[list]:

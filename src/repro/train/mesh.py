@@ -93,6 +93,7 @@ from repro.core.grad import weighted_psum, weighted_psum_with_sqnorm
 from repro.het.simulator import WorkerSpec
 from repro.launch.mesh import data_axes
 from repro.optim.optimizers import Optimizer
+from repro.train import spans
 from repro.train.engine import EventEngine
 from repro.train.loop import OuterBatchMixin, StepRecord, TrainConfig
 
@@ -292,6 +293,15 @@ class MeshTrainer(OuterBatchMixin):
         self.accum_calls = 0       # jitted training executions
         self.accum_traces = 0      # XLA traces (one per distinct bucket)
         self.timing_reruns = 0     # post-compile re-executions (timing only)
+        self.rows_valid = 0        # rows dispatched that the mask counts
+        self.rows_padded = 0       # bucket rows past them (mask 0)
+        # gradient bytes put onto the full mesh after the workers' slices:
+        # each leaf's bytes times the chips it was not on yet
+        self.exchange_bytes = 0
+        self._compiles0 = spans.compiles()
+        # the BSP round in flight's seconds per span name (None outside)
+        self._phases: Optional[dict] = None
+        self._transfer = None      # the round's transfer awaiter (a future)
         # (dispatch_ts, completion_ts) per worker for the last concurrent
         # BSP round (concurrency diagnostics; None until one ran)
         self.last_round_stamps: Optional[list[tuple[float, float]]] = None
@@ -320,6 +330,7 @@ class MeshTrainer(OuterBatchMixin):
         self._exec: list[_WorkerExec] = []
         self._pool: Optional[ThreadPoolExecutor] = None
         self._pool_size = 0
+        self._transfer_thread: Optional[ThreadPoolExecutor] = None
         self._reconfigure_execution()
         # --- measurement state + event queue ---
         self._ewma: list[Optional[float]] = [None] * self.k
@@ -451,6 +462,30 @@ class MeshTrainer(OuterBatchMixin):
                 max_workers=self._pool_size, thread_name_prefix="mesh-await")
         return self._pool
 
+    def _transfer_pool(self) -> ThreadPoolExecutor:
+        """The transfer span's own thread: it may still wait on a round's
+        moved gradients when the next round's awaiters start, so it must
+        not hold one of theirs."""
+        if self._transfer_thread is None:
+            self._transfer_thread = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="mesh-transfer")
+        return self._transfer_thread
+
+    def _span(self, name: str, **ids):
+        """A span of the round (``repro.train.spans``), its seconds added
+        to the round's phases while a BSP round is in flight."""
+        return spans.span(name, self._phases, round=self.step_idx, **ids)
+
+    @property
+    def compiles(self) -> int:
+        """Backend compiles in this process since the trainer was built."""
+        return spans.compiles()[0] - self._compiles0[0]
+
+    @property
+    def compile_s(self) -> float:
+        """Their seconds."""
+        return spans.compiles()[1] - self._compiles0[1]
+
     # ------------------------------------------------------------- planning
 
     def bucket_for(self, worker: int, batch: int) -> int:
@@ -533,25 +568,29 @@ class MeshTrainer(OuterBatchMixin):
         is still a prefix.  Don't reorder rows here without updating that
         derivation.
         """
-        rec = self._exec[worker]
-        bucket = self.bucket_for(worker, batch_size)
-        self.worker_buckets[worker].add(bucket)
-        host_data = self.next_batch(worker, bucket)
-        mask_host = (np.arange(bucket) < batch_size).astype(np.float32)
-        data = jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, rec.data_sharding), host_data)
-        mask = jax.device_put(jnp.asarray(mask_host), rec.data_sharding)
-        # pin params to ONE canonical sharding (replicated over the worker's
-        # mesh): each slice needs its own replica anyway (a per-slice jit
-        # may not mix device sets with the full mesh), and a drifting input
-        # sharding (uncommitted init params vs committed post-update params)
-        # would trigger silent re-LOWERS — recompiles with no fresh trace —
-        # that the compile-time exclusion below could not detect
-        params = jax.device_put(self.params, rec.params_sharding)
-        traces_before = self.accum_traces
-        t0 = _time.perf_counter()
-        out = rec.gradfn(params, data, mask)
-        self.accum_calls += 1
+        with self._span("dispatch", worker=worker):
+            rec = self._exec[worker]
+            bucket = self.bucket_for(worker, batch_size)
+            self.worker_buckets[worker].add(bucket)
+            self.rows_valid += batch_size
+            self.rows_padded += bucket - batch_size
+            host_data = self.next_batch(worker, bucket)
+            mask_host = (np.arange(bucket) < batch_size).astype(np.float32)
+            data = jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, rec.data_sharding), host_data)
+            mask = jax.device_put(jnp.asarray(mask_host), rec.data_sharding)
+            # pin params to ONE canonical sharding (replicated over the
+            # worker's mesh): each slice needs its own replica anyway (a
+            # per-slice jit may not mix device sets with the full mesh), and
+            # a drifting input sharding (uncommitted init params vs committed
+            # post-update params) would trigger silent re-LOWERS —
+            # recompiles with no fresh trace — that the compile-time
+            # exclusion below could not detect
+            params = jax.device_put(self.params, rec.params_sharding)
+            traces_before = self.accum_traces
+            t0 = _time.perf_counter()
+            out = rec.gradfn(params, data, mask)
+            self.accum_calls += 1
         return _Dispatch(
             worker=worker, out=out, t0=t0,
             fresh_trace=self.accum_traces > traces_before,
@@ -562,15 +601,17 @@ class MeshTrainer(OuterBatchMixin):
         tracing+compilation, so re-run once, alone, from the same host data
         (pure function — result identical and discarded)."""
         self.timing_reruns += 1
-        rec = self._exec[d.worker]
-        data = jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, rec.data_sharding), d.host_data)
-        mask = jax.device_put(jnp.asarray(d.mask_host), rec.data_sharding)
-        params = jax.device_put(self.params, rec.params_sharding)
-        t0 = _time.perf_counter()
-        rerun = rec.gradfn(params, data, mask)
-        jax.block_until_ready(rerun)
-        return _time.perf_counter() - t0
+        with self._span("rerun", worker=d.worker):
+            rec = self._exec[d.worker]
+            data = jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, rec.data_sharding), d.host_data)
+            mask = jax.device_put(jnp.asarray(d.mask_host),
+                                  rec.data_sharding)
+            params = jax.device_put(self.params, rec.params_sharding)
+            t0 = _time.perf_counter()
+            rerun = rec.gradfn(params, data, mask)
+            jax.block_until_ready(rerun)
+            return _time.perf_counter() - t0
 
     def _measured_worker_grad(self, worker: int, batch_size: int):
         """One device-synced, timed gradient call for ``worker`` (solo).
@@ -581,14 +622,14 @@ class MeshTrainer(OuterBatchMixin):
         through here; concurrent BSP rounds use ``_dispatch`` directly.
         """
         d = self._dispatch(worker, batch_size)
-        jax.block_until_ready(d.out)
-        dt = _time.perf_counter() - d.t0
+        with self._span("await", worker=worker):
+            jax.block_until_ready(d.out)
+            dt = _time.perf_counter() - d.t0
+            loss_sum, w_sum = float(d.out[1]), float(d.out[2])
+            self._last_sqnorm = float(d.out[3]) if len(d.out) > 3 else None
         if d.fresh_trace:
             dt = self._solo_rerun(d)
-        g_mean, loss_sum, w_sum = d.out[:3]
-        self._last_sqnorm = float(d.out[3]) if len(d.out) > 3 else None
-        return (g_mean, float(loss_sum), float(w_sum),
-                dt * self.dilation[worker])
+        return d.out[0], loss_sum, w_sum, dt * self.dilation[worker]
 
     def _observe_time(self, worker: int, seconds: float) -> float:
         """EWMA filter over measured step times (measurement pipeline; the
@@ -630,7 +671,8 @@ class MeshTrainer(OuterBatchMixin):
         """Stamp per-slice completions; gather grads, losses, raw times."""
         if futures is None:
             futures = self._submit_awaiters(dispatches)
-        stamps = [f.result() for f in futures]
+        with self._span("await"):
+            stamps = [f.result() for f in futures]
         # (dispatch, completion) per worker, for concurrency diagnostics:
         # max(dispatch) < min(completion) ⇔ all K calls were in flight at
         # once (benchmarks/backend_bench.py asserts this)
@@ -639,20 +681,30 @@ class MeshTrainer(OuterBatchMixin):
         self.last_round_devices = [
             {frozenset(x.sharding.device_set) for x in jax.tree.leaves(d.out)}
             for d in dispatches]
-        grads, losses, weights, raw_times, sqnorms = [], 0.0, 0.0, [], []
-        for d, done in zip(dispatches, stamps):
-            dt = done - d.t0
-            if d.fresh_trace:
-                dt = self._solo_rerun(d)
-            g_mean, loss_sum, w_sum = d.out[:3]
-            # slice-committed grads must rejoin the full mesh before the
-            # driver-side lambda combine
-            grads.append(jax.device_put(g_mean, self._full_replicated))
-            losses += float(loss_sum)
-            weights += float(w_sum)
-            raw_times.append(dt * self.dilation[d.worker])
-            if len(d.out) > 3:
-                sqnorms.append(float(d.out[3]))
+        raw_times = [
+            (self._solo_rerun(d) if d.fresh_trace else done - d.t0)
+            * self.dilation[d.worker] for d, done in zip(dispatches, stamps)]
+        grads, losses, weights, sqnorms = [], 0.0, 0.0, []
+        with self._span("exchange"):
+            t_issue = _time.perf_counter()
+            to = self._full_replicated.device_set
+            for d in dispatches:
+                g_mean, loss_sum, w_sum = d.out[:3]
+                # slice-committed grads must rejoin the full mesh before the
+                # host-side lambda combine
+                grads.append(jax.device_put(g_mean, self._full_replicated))
+                self.exchange_bytes += sum(
+                    x.nbytes * len(to - x.sharding.device_set)
+                    for x in jax.tree.leaves(g_mean))
+                losses += float(loss_sum)
+                weights += float(w_sum)
+                if len(d.out) > 3:
+                    sqnorms.append(float(d.out[3]))
+            # the moved gradients are no XLA op: a span on a thread of its
+            # own covers them until they are ready on every chip
+            self._transfer = self._transfer_pool().submit(
+                spans.span_until_ready, "transfer", grads, t_issue,
+                round=self.step_idx)
         return grads, losses, weights, raw_times, sqnorms
 
     def _round_sequential(self):
@@ -676,6 +728,7 @@ class MeshTrainer(OuterBatchMixin):
         return raw_times
 
     def bsp_step(self) -> StepRecord:
+        self._phases = phases = {}
         pre_batches = list(self.batches)
         if self.concurrent and self.k > 1:
             grads, losses, weights, raw_times, sqnorms = \
@@ -683,45 +736,53 @@ class MeshTrainer(OuterBatchMixin):
         else:
             grads, losses, weights, raw_times, sqnorms = \
                 self._round_sequential()
-        raw_times = self._charge_interference(raw_times)
-        smoothed = [self._observe_time(k, t) for k, t in enumerate(raw_times)]
-        for k, t in enumerate(raw_times):
-            self.time_model.observe(k, self.batches[k], t)
-        # Eq. 2-3: lambda-weighted combine (identical to the sim path)
-        if self._need_grad_stats:
-            g, g_sqnorm = combine_weighted_with_sqnorm(grads, self.batches)
-            g_sqnorm = float(g_sqnorm)
-        else:
-            g = combine_weighted(grads, self.batches)
-            g_sqnorm = None
-        if self.reserve and not self.concurrent:
-            # fallback grads live on the train-region submesh (the serve
-            # reserve is excluded); rejoin the full mesh so params stay
-            # replicated everywhere across serve-slice resizes
-            g = jax.device_put(g, self._full_replicated)
-        self.params, self.opt_state = self._opt_update(
-            self.params, g, self.opt_state, jnp.asarray(self.step_idx))
-        # the engine's barrier consumes the round's MEASURED times (same
-        # semantics as the sim backend's StepRecord) and keeps the shared
-        # version counter BSP and ASP staleness both read; only the
-        # controller sees the EWMA-filtered view
-        self.time_model.push_round(raw_times)
-        info = self.engine.bsp_round(self.batches)
-        adjusted = False
-        if self.controller is not None:
-            upd = self.controller.observe(smoothed)
-            adjusted = upd.updated
-            self.batches = upd.batches
-        if self._observe_outer(
-                loss=losses / max(weights, 1e-9),
-                seconds=info["iteration_time"],
-                sqnorms=sqnorms or None, pre_batches=pre_batches,
-                combined_sqnorm=g_sqnorm,
-                worker_times=raw_times):
-            # a B_global resize needs NO slice replan: slices keep their
-            # widths, each worker's grown batch just walks its own bucket
-            # ladder — the §11 recompile bound is the ladder length
-            adjusted = True
+        with self._span("control"):
+            raw_times = self._charge_interference(raw_times)
+            smoothed = [self._observe_time(k, t)
+                        for k, t in enumerate(raw_times)]
+            for k, t in enumerate(raw_times):
+                self.time_model.observe(k, self.batches[k], t)
+        with self._span("combine"):
+            # Eq. 2-3: lambda-weighted combine (identical to the sim path)
+            if self._need_grad_stats:
+                g, g_sqnorm = combine_weighted_with_sqnorm(grads,
+                                                           self.batches)
+                g_sqnorm = float(g_sqnorm)
+            else:
+                g = combine_weighted(grads, self.batches)
+                g_sqnorm = None
+            if self.reserve and not self.concurrent:
+                # fallback grads live on the train-region submesh (the
+                # serve reserve is excluded); rejoin the full mesh so params
+                # stay replicated everywhere across serve-slice resizes
+                g = jax.device_put(g, self._full_replicated)
+        with self._span("update"):
+            self.params, self.opt_state = self._opt_update(
+                self.params, g, self.opt_state, jnp.asarray(self.step_idx))
+        with self._span("control"):
+            # the engine's barrier consumes the round's MEASURED times (same
+            # semantics as the sim backend's StepRecord) and keeps the
+            # shared version counter BSP and ASP staleness both read; only
+            # the controller sees the EWMA-filtered view
+            self.time_model.push_round(raw_times)
+            info = self.engine.bsp_round(self.batches)
+            adjusted = False
+            if self.controller is not None:
+                upd = self.controller.observe(smoothed)
+                adjusted = upd.updated
+                self.batches = upd.batches
+            if self._observe_outer(
+                    loss=losses / max(weights, 1e-9),
+                    seconds=info["iteration_time"],
+                    sqnorms=sqnorms or None, pre_batches=pre_batches,
+                    combined_sqnorm=g_sqnorm,
+                    worker_times=raw_times):
+                # a B_global resize needs NO slice replan: slices keep their
+                # widths, each worker's grown batch just walks its own
+                # bucket ladder — the §11 recompile bound is the ladder
+                # length
+                adjusted = True
+        self._phases = None
         rec = StepRecord(
             step=self.step_idx,
             sim_time=self.time_model.time,
@@ -731,7 +792,14 @@ class MeshTrainer(OuterBatchMixin):
             adjusted=adjusted,
             straggler_waste=info["straggler_waste"],
             worker_times=list(raw_times),
+            phases=phases,
         )
+        if self._transfer is not None:
+            # a fresh dict, not an update of the one readers may hold
+            self._transfer.add_done_callback(
+                lambda f: setattr(rec, "phases",
+                                  {**rec.phases, "transfer": f.result()}))
+            self._transfer = None
         self.history.append(rec)
         self.step_idx += 1
         return rec

@@ -262,13 +262,23 @@ class TestMeshBackend:
         # the global batch survives both membership events
         assert sum(out["final_batches"]) == sum(out["history"][0].batches)
 
-    def test_asp_converges_like_sim(self):
+    def test_asp_converges_like_sim(self, monkeypatch):
         """Mesh ASP (DESIGN.md §12): the measured-time event queue drives
         staleness-weighted updates, and the closed loop lands on the same
         allocation *ordering* as the golden sim-ASP run of the identical
-        experiment (slowest declared worker smallest batch).  The CNN, not
-        linreg: a linreg step takes about 0.1 ms on a CPU, under the jitter
-        of dispatch, so its measured times carry no batch-size signal."""
+        experiment (slowest declared worker smallest batch).  A CNN step's
+        wall time on a CPU shared with other test processes is noise that
+        can reorder two workers, so each call's measured seconds are
+        replaced by a clock of the rows it computed (1 ms a row), which the
+        emulated dilation then scales as it scales a measured time."""
+        measured = MeshTrainer._measured_worker_grad
+
+        def row_clock(trainer, worker, batch_size):
+            g, loss_sum, w_sum, _ = measured(trainer, worker, batch_size)
+            return g, loss_sum, w_sum, 1e-3 * w_sum * trainer.dilation[worker]
+
+        monkeypatch.setattr(MeshTrainer, "_measured_worker_grad", row_clock)
+
         def experiment(backend):
             return _experiment(backend=backend, workload="mnist-cnn",
                                sync="asp", max_steps=18)
