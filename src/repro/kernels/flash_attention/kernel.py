@@ -19,8 +19,18 @@ TPU-native design (DESIGN.md §14):
     backward call;
   * GQA is expressed in the K/V index_map (query head h reads kv head
     h // rep) — no materialized head repetition in HBM;
+  * the tile is chosen from the shape (``tile_plan``): the widest of 512,
+    256 and 128 that divides the sequence, no wider than a sliding window
+    rounded up to 128.  A grid step costs a fixed ~0.35 us on a TPU v5e
+    whatever its tile holds, so at seq 4096 512-wide tiles launch 64 steps
+    per (row, head) where 128-wide ones launch 1024;
   * causal + sliding-window masking is applied per tile; fully-masked tiles
-    short-circuit via @pl.when so the MXU never sees them;
+    short-circuit via @pl.when so the MXU never sees them, and their index
+    maps are clamped into the visible band (``_kv_band``/``_q_band``): a
+    masked step names the block its neighbouring visible step holds, and
+    the pipeline copies nothing for a block index that repeats (the
+    reference TPU kernel's ``below_or_on_diag`` trick), so a masked step
+    moves no data;
   * float32 inputs get float32 MXU passes (``precision=HIGHEST``): Mosaic's
     default runs a float32 dot as one bfloat16 pass.
 
@@ -57,7 +67,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -125,9 +135,86 @@ def _guarded(gate, fn):
         pl.when(gate)(fn)
 
 
+class TilePlan(NamedTuple):
+    """The tile schedule of one (row, head) of the causal grid."""
+    block_q: int
+    block_k: int
+    grid_steps: int     # programs each kernel launches: (seq_q/bq)(seq_k/bk)
+    visible_tiles: int  # of those, the ones holding a visible (q, k) pair
+
+
+TILES = (512, 256, 128)  # widest first; (1024, 512) overflows the bwd's VMEM
+
+
+def _auto_block(n: int, cap: Optional[int]) -> int:
+    """The widest of TILES dividing ``n`` and at most ``cap``; sequences
+    no tile divides keep the old default, min(128, n)."""
+    for tile in TILES:
+        if n % tile == 0 and (cap is None or tile <= cap):
+            return tile
+    return min(LANE, n)
+
+
+def tile_plan(seq_q: int, seq_k: int, *, causal: bool = True,
+              window: Optional[int] = None, block_q: Optional[int] = None,
+              block_k: Optional[int] = None) -> TilePlan:
+    """Blocks for a (seq_q, seq_k) attention and what they cost per head.
+
+    A block left None is chosen from the shape: the widest tile that
+    divides the sequence, no wider than the window rounded up to the lane
+    width (a wider tile would hold mostly masked pairs).  An explicit
+    block is kept, capped at its sequence."""
+    cap = None if window is None else _ceil_to(window, LANE)
+    bq = _auto_block(seq_q, cap) if block_q is None else min(block_q, seq_q)
+    bk = _auto_block(seq_k, cap) if block_k is None else min(block_k, seq_k)
+    if seq_q % bq or seq_k % bk:
+        raise ValueError(
+            f"seq ({seq_q},{seq_k}) must divide blocks ({bq},{bk})")
+    nq, nk = seq_q // bq, seq_k // bk
+    visible = sum(bool(_tile_visible(
+        iq, ik, block_q=bq, block_k=bk, seq_q=seq_q, seq_k=seq_k,
+        causal=causal, window=window)) for iq in range(nq) for ik in range(nk))
+    return TilePlan(bq, bk, nq * nk, visible)
+
+
+def _kv_band(iq, nk, *, block_q, block_k, seq_q, seq_k, causal, window):
+    """[first, last] kv blocks ``_tile_visible`` passes for q block ``iq``
+    (traced), clipped to the grid; empty when first > last; the whole
+    grid when nothing masks."""
+    q_first = iq * block_q + (seq_k - seq_q)
+    lo, hi = 0, nk - 1
+    if causal:
+        hi = jnp.clip((q_first + block_q - 1) // block_k, -1, nk - 1)
+    if window is not None:
+        lo = jnp.clip((q_first - window + 1) // block_k, 0, nk)
+    return lo, hi
+
+
+def _q_band(ik, nq, *, block_q, block_k, seq_q, seq_k, causal, window):
+    """[first, last] q blocks ``_tile_visible`` passes for kv block ``ik``
+    (traced), clipped to the grid; empty when first > last."""
+    k_first = ik * block_k - (seq_k - seq_q)   # in query coordinates
+    lo, hi = 0, nq - 1
+    if causal:
+        lo = jnp.clip(k_first // block_q, 0, nq)
+    if window is not None:
+        hi = jnp.clip((k_first + block_k + window - 2) // block_q, -1, nq - 1)
+    return lo, hi
+
+
+def _in_band(i, band):
+    """Block index ``i`` moved into its band, so that a step the kernel
+    skips names the block a neighbouring visible step already holds and
+    the pipeline issues no copy for it.  Visible steps are unchanged; an
+    empty band still yields an index inside the grid."""
+    lo, hi = band
+    return jnp.minimum(jnp.maximum(i, lo), jnp.maximum(hi, 0))
+
+
 def _tile_visible(iq, ik, *, block_q, block_k, seq_q, seq_k, causal, window):
     """Scalar predicate: does tile (iq, ik) contain any visible (q, k) pair?
-    (queries right-aligned when seq_q < seq_k: decode)"""
+    (queries right-aligned when seq_q < seq_k: decode; Python ints give a
+    Python bool)"""
     q_first = iq * block_q + (seq_k - seq_q)
     q_last = q_first + block_q - 1
     k_first = ik * block_k
@@ -137,7 +224,7 @@ def _tile_visible(iq, ik, *, block_q, block_k, seq_q, seq_k, causal, window):
         visible = k_first <= q_last
     if window is not None:
         vis_w = k_last > q_first - window
-        visible = jnp.logical_and(visible, vis_w) if causal else vis_w
+        visible = visible & vis_w
     return visible
 
 
@@ -282,15 +369,17 @@ def _fwd_call(q, k, v, nv, *, causal, window, softcap, sm_scale,
     t, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
     nb, nv = _grid_rows(nv, b)
+    geom = dict(block_q=block_q, block_k=block_k, seq_q=s, seq_k=t,
+                causal=causal, window=window)
     kernel = functools.partial(
-        _fwd_kernel, block_q=block_q, block_k=block_k, seq_q=s, seq_k=t,
-        causal=causal, window=window, softcap=softcap, sm_scale=sm_scale,
+        _fwd_kernel, **geom, softcap=softcap, sm_scale=sm_scale,
         ragged=nv is not None)
 
     def q_at(b_, h_, iq, ik, nvr=None):
         return (_bsel(b_, nvr), iq, h_)
 
     def kv_at(b_, h_, iq, ik, nvr=None):
+        ik = _in_band(ik, _kv_band(iq, t // block_k, **geom))
         return (_bsel(b_, nvr), ik, h_ // rep)
 
     out, lse = _pallas(
@@ -333,7 +422,8 @@ def _fwd_rowloop(q, k, v, nv, **kw):
 def flash_attention(q, k, v, *, num_valid=None, ragged_impl: str = "auto",
                     causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False, return_lse: bool = False):
     """q: (B,S,H,D), k/v: (B,T,Hkv,D) with H % Hkv == 0 -> (B,S,H,D).
 
@@ -341,20 +431,18 @@ def flash_attention(q, k, v, *, num_valid=None, ragged_impl: str = "auto",
     grid (not just masked) and their outputs are exact zeros; one compile
     per bucket shape covers every valid count.  return_lse additionally
     returns the per-row logsumexp (B,H,S) f32 residual for the backward
-    kernels (zeros on padded rows).
+    kernels (zeros on padded rows).  block_q/block_k: None chooses the
+    tile from the shape (``tile_plan``).
     """
     b, s, h, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     if h % hkv:
         raise ValueError(f"H={h} not divisible by Hkv={hkv}")
-    block_q = min(block_q, s)
-    block_k = min(block_k, t)
-    if s % block_q or t % block_k:
-        raise ValueError(
-            f"seq ({s},{t}) must divide blocks ({block_q},{block_k})")
+    plan = tile_plan(s, t, causal=causal, window=window, block_q=block_q,
+                     block_k=block_k)
     kw = dict(causal=causal, window=window, softcap=softcap,
-              sm_scale=1.0 / math.sqrt(d), block_q=block_q, block_k=block_k,
-              interpret=interpret)
+              sm_scale=1.0 / math.sqrt(d), block_q=plan.block_q,
+              block_k=plan.block_k, interpret=interpret)
     qp, kp, vp = _pad_lanes(q), _pad_lanes(k), _pad_lanes(v)
 
     if num_valid is None:
@@ -498,9 +586,9 @@ def _bwd_call(q, k, v, do, lse, delta, nv, *, causal, window, softcap,
     t, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
     nb, nv = _grid_rows(nv, b)
-    kw = dict(block_q=block_q, block_k=block_k, seq_q=s, seq_k=t,
-              causal=causal, window=window, softcap=softcap,
-              sm_scale=sm_scale, ragged=nv is not None)
+    geom = dict(block_q=block_q, block_k=block_k, seq_q=s, seq_k=t,
+                causal=causal, window=window)
+    kw = dict(geom, softcap=softcap, sm_scale=sm_scale, ragged=nv is not None)
     nq, nk = s // block_q, t // block_k
     args = (_fold(q), _fold(k), _fold(v), _fold(do), _row_stat(lse),
             _row_stat(delta))
@@ -511,6 +599,7 @@ def _bwd_call(q, k, v, do, lse, delta, nv, *, causal, window, softcap,
         return (_bsel(b_, nvr), i2, h_)
 
     def kv_at_3(b_, h_, i2, i3, nvr=None):
+        i3 = _in_band(i3, _kv_band(i2, nk, **geom))
         return (_bsel(b_, nvr), i3, h_ // rep)
 
     def row_at_2(b_, h_, i2, i3, nvr=None):
@@ -531,12 +620,14 @@ def _bwd_call(q, k, v, do, lse, delta, nv, *, causal, window, softcap,
 
     # ---- dkv: grid (B, H, nk, nq), accumulate over the trailing q axis ----
     def q_at_3(b_, h_, i2, i3, nvr=None):
+        i3 = _in_band(i3, _q_band(i2, nq, **geom))
         return (_bsel(b_, nvr), i3, h_)
 
     def kv_at_2(b_, h_, i2, i3, nvr=None):
         return (_bsel(b_, nvr), i2, h_ // rep)
 
     def row_at_3(b_, h_, i2, i3, nvr=None):
+        i3 = _in_band(i3, _q_band(i2, nq, **geom))
         return (_bsel(b_, nvr), h_, i3, 0)
 
     def out_kv_at_2(b_, h_, i2, i3, nvr=None):
@@ -587,7 +678,8 @@ def flash_attention_bwd(q, k, v, do, out, lse, *, num_valid=None,
                         ragged_impl: str = "auto", causal: bool = True,
                         window: Optional[int] = None,
                         softcap: Optional[float] = None,
-                        block_q: int = 128, block_k: int = 128,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
                         interpret: bool = False):
     """Pallas backward: (dq, dk, dv) for the flash_attention forward.
 
@@ -597,18 +689,15 @@ def flash_attention_bwd(q, k, v, do, out, lse, *, num_valid=None,
     b, s, h, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
-    block_q = min(block_q, s)
-    block_k = min(block_k, t)
-    if s % block_q or t % block_k:
-        raise ValueError(
-            f"seq ({s},{t}) must divide blocks ({block_q},{block_k})")
+    plan = tile_plan(s, t, causal=causal, window=window, block_q=block_q,
+                     block_k=block_k)
     # delta = rowsum(dO . O): the only extra residual the flash backward
     # needs beyond lse; (B, H, S) f32 like lse
     delta = (do.astype(jnp.float32) * out.astype(jnp.float32)) \
         .sum(-1).transpose(0, 2, 1)
     kw = dict(causal=causal, window=window, softcap=softcap,
-              sm_scale=1.0 / math.sqrt(d), block_q=block_q, block_k=block_k,
-              interpret=interpret)
+              sm_scale=1.0 / math.sqrt(d), block_q=plan.block_q,
+              block_k=plan.block_k, interpret=interpret)
     qp, kp, vp, dop = (_pad_lanes(x) for x in (q, k, v, do))
 
     if num_valid is None:

@@ -85,8 +85,9 @@ _attention.defvjp(_fwd, _bwd)
                      "interpret", "use_kernel", "bwd_impl", "ragged_impl"))
 def attention(q, k, v, *, num_valid=None, causal: bool = True,
               window: Optional[int] = None,
-              softcap: Optional[float] = None, block_q: int = 128,
-              block_k: int = 128, interpret: bool = False,
+              softcap: Optional[float] = None,
+              block_q: Optional[int] = None, block_k: Optional[int] = None,
+              interpret: bool = False,
               use_kernel: bool = True, bwd_impl: str = "pallas",
               ragged_impl: str = "auto"):
     """Differentiable attention on the kernel (or reference) backend.
@@ -96,6 +97,7 @@ def attention(q, k, v, *, num_valid=None, causal: bool = True,
     requires the trainer's suffix-padding contract (valid rows form a
     prefix — train/mesh.py).  bwd_impl: "pallas" (default) or "oracle"
     (jnp recompute reference).  ragged_impl: see kernels/.../kernel.py.
+    block_q/block_k: None lets ``kernel.tile_plan`` choose from the shape.
     """
     if bwd_impl not in ("pallas", "oracle"):
         raise ValueError(f"unknown bwd_impl {bwd_impl!r}")

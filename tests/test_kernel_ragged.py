@@ -147,26 +147,33 @@ def test_single_executable_serves_all_valid_counts():
 # -------------------------------------------------------- Pallas backward
 
 BWD_CASES = [
-    # (b, s, t, h, hkv, d, causal, window, softcap)
-    (2, 128, 128, 4, 4, 64, True, None, None),    # MHA, whisper head_dim
-    (2, 128, 128, 4, 2, 64, True, None, None),    # GQA
-    (1, 256, 256, 4, 1, 32, True, None, None),    # MQA, d=32 lane pad
-    (1, 256, 256, 4, 2, 64, True, 64, None),      # sliding window
-    (2, 128, 128, 2, 2, 64, True, None, 30.0),    # softcap chain rule
-    (2, 128, 128, 4, 4, 64, False, None, None),   # bidirectional
-    (1, 128, 128, 2, 1, 256, True, None, None),   # full-lane head_dim
+    # (b, s, t, h, hkv, d, causal, window, softcap, valid rows); the later
+    # shapes get tiles wider than 128 from tile_plan, so the index maps
+    # clamped to the visible band are checked on each of its edges
+    (2, 128, 128, 4, 4, 64, True, None, None, 2),    # MHA, whisper head_dim
+    (2, 128, 128, 4, 2, 64, True, None, None, 2),    # GQA
+    (1, 256, 256, 4, 1, 32, True, None, None, 1),    # MQA, d=32 lane pad
+    (1, 256, 256, 4, 2, 64, True, 64, None, 1),      # sliding window
+    (2, 128, 128, 2, 2, 64, True, None, 30.0, 2),    # softcap chain rule
+    (2, 128, 128, 4, 4, 64, False, None, None, 2),   # bidirectional
+    (1, 128, 128, 2, 1, 256, True, None, None, 1),   # full-lane head_dim
+    (3, 1024, 1024, 4, 2, 96, True, None, None, 2),  # 512 tiles, GQA, ragged
+    (1, 1024, 1024, 2, 1, 64, True, 200, None, 1),   # window band edges
+    (1, 512, 1536, 2, 2, 96, True, 300, None, 1),    # q shorter, windowed
 ]
 
 
 @pytest.mark.parametrize("case", BWD_CASES)
 def test_pallas_bwd_matches_oracle(case):
     """Dedicated backward kernels vs the jnp recompute oracle."""
-    b, s, t, h, hkv, d, causal, window, cap = case
+    b, s, t, h, hkv, d, causal, window, cap, nv = case
     q, k, v, w = _data(b, seed=6, s=s, h=h, hkv=hkv, d=d, t=t)
     kw = dict(causal=causal, window=window, softcap=cap)
-    _, gp = _vg(True, bwd_impl="pallas", **kw)(q, k, v, jnp.int32(b), w)
-    _, go = _vg(True, bwd_impl="oracle", **kw)(q, k, v, jnp.int32(b), w)
+    _, gp = _vg(True, bwd_impl="pallas", **kw)(q, k, v, jnp.int32(nv), w)
+    _, go = _vg(True, bwd_impl="oracle", **kw)(q, k, v, jnp.int32(nv), w)
     _assert_grads_close(gp, go)
+    for g in gp:
+        assert not np.any(np.asarray(g[nv:]))
 
 
 def test_pallas_bwd_matches_oracle_ragged():

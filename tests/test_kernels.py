@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import attention_ref, flash_attention
+from repro.kernels.flash_attention.kernel import tile_plan
 from repro.kernels.rglru_scan import rglru_linear_scan, rglru_scan
 from repro.kernels.ssd_scan import ssd, ssd_chunked
 
@@ -16,27 +17,31 @@ KEY = jax.random.PRNGKey(42)
 # ------------------------------------------------------------ flash attention
 
 FLASH_CASES = [
-    # (b, s, t, h, hkv, d, causal, window, softcap)
-    (2, 128, 128, 4, 4, 64, True, None, None),    # MHA
-    (2, 128, 128, 4, 2, 64, True, None, None),    # GQA
-    (1, 256, 256, 4, 1, 32, True, None, None),    # MQA
-    (1, 256, 256, 4, 2, 64, True, 64, None),      # sliding window
-    (2, 128, 128, 2, 2, 64, True, None, 30.0),    # grok-style softcap
-    (2, 128, 128, 4, 4, 64, False, None, None),   # bidirectional
-    (1, 128, 256, 4, 2, 64, True, None, None),    # q shorter than kv
-    (1, 128, 128, 2, 1, 256, True, None, None),   # gemma head_dim 256
+    # (b, s, t, h, hkv, d, causal, window, softcap, block); block None
+    # lets tile_plan choose (tiles wider than 128 on these shapes)
+    (2, 128, 128, 4, 4, 64, True, None, None, 64),    # MHA
+    (2, 128, 128, 4, 2, 64, True, None, None, 64),    # GQA
+    (1, 256, 256, 4, 1, 32, True, None, None, 64),    # MQA
+    (1, 256, 256, 4, 2, 64, True, 64, None, 64),      # sliding window
+    (2, 128, 128, 2, 2, 64, True, None, 30.0, 64),    # grok-style softcap
+    (2, 128, 128, 4, 4, 64, False, None, None, 64),   # bidirectional
+    (1, 128, 256, 4, 2, 64, True, None, None, 64),    # q shorter than kv
+    (1, 128, 128, 2, 1, 256, True, None, None, 64),   # gemma head_dim 256
+    (1, 1024, 1024, 4, 2, 96, True, None, None, None),  # 512 tiles, GQA
+    (1, 1024, 1024, 2, 1, 64, True, 200, None, None),   # window band edges
+    (1, 512, 1536, 2, 1, 64, True, 300, None, None),    # q shorter, window
 ]
 
 
 @pytest.mark.parametrize("case", FLASH_CASES)
 def test_flash_attention_matches_ref(case):
-    b, s, t, h, hkv, d, causal, window, cap = case
+    b, s, t, h, hkv, d, causal, window, cap, block = case
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
     k = jax.random.normal(ks[1], (b, t, hkv, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, t, hkv, d), jnp.float32)
     out = flash_attention(q, k, v, causal=causal, window=window, softcap=cap,
-                          block_q=64, block_k=64, interpret=True)
+                          block_q=block, block_k=block, interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window, softcap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -67,6 +72,32 @@ def test_flash_attention_block_shapes(block):
     ref = attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+TILE_PLAN_CASES = [
+    # (seq_q, seq_k, window, explicit blocks) -> (block_q, block_k, grid
+    # steps, visible tiles) per head
+    ((128, 128, None, None), (128, 128, 1, 1)),
+    ((384, 384, None, None), (128, 128, 9, 6)),       # 384 = 3 x 128
+    ((640, 640, None, None), (128, 128, 25, 15)),     # 640 = 5 x 128
+    ((768, 768, None, None), (256, 256, 9, 6)),
+    ((4096, 4096, None, None), (512, 512, 64, 36)),   # both cells
+    ((512, 1536, None, None), (512, 512, 3, 3)),      # q right-aligned
+    ((4096, 4096, 64, None), (128, 128, 1024, 63)),   # window caps the tile
+    ((4096, 4096, 200, None), (256, 256, 256, 31)),   # cap rounds up to 256
+    ((4096, 4096, 4096, None), (512, 512, 64, 36)),   # wide window: no cap
+    ((4096, 4096, None, (32, 64)), (32, 64, 8192, 4160)),  # explicit blocks
+    ((128, 128, None, (256, 256)), (128, 128, 1, 1)),  # capped at the seq
+    ((64, 64, None, None), (64, 64, 1, 1)),           # shorter than a lane
+]
+
+
+@pytest.mark.parametrize("case", TILE_PLAN_CASES)
+def test_tile_plan(case):
+    (s, t, window, blocks), want = case
+    bq, bk = blocks or (None, None)
+    assert tuple(tile_plan(s, t, window=window, block_q=bq,
+                           block_k=bk)) == want
 
 
 def test_flash_attention_rejects_bad_shapes():

@@ -1,11 +1,14 @@
 """Compile the main-path Pallas kernel for a described TPU v5e chip.
 
 Interpret mode runs a kernel's body on the CPU and never asks the chip's
-compiler (Mosaic) whether its blocks tile; these tests do, with no chip
-attached.  The flash-attention forward on the ragged grid, and forward
-plus backward through ``ops.attention``, at Yi-9B's head geometry and the
-smoke run's shapes (configs/yi_9b.py, chip_smoke.py): a 4-row bucket of
-4096 tokens, 32 query heads and 4 KV heads of 128.
+compiler (Mosaic) whether its blocks tile or fit in VMEM; these tests do,
+with no chip attached.  The flash-attention forward on the ragged grid,
+and forward plus backward through ``ops.attention``, at Yi-9B's head
+geometry and the smoke run's shapes (configs/yi_9b.py, chip_smoke.py): a
+4-row bucket of 4096 tokens, 32 query heads and 4 KV heads of 128.  The
+forward plus backward also compiles at the largest bucket of each
+benchmark cell, in float32 with the tiles ``tile_plan`` chooses: Phi-3's
+7 rows of 32 MHA heads of 96, Yi-9B's 6 rows of 32/4 heads of 128.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -45,9 +48,9 @@ def one_chip():
         compilation_cache.reset_cache()
 
 
-def _inputs(sharding, dtype):
-    q = jax.ShapeDtypeStruct((B, S, H, D), dtype, sharding=sharding)
-    kv = jax.ShapeDtypeStruct((B, S, HKV, D), dtype, sharding=sharding)
+def _inputs(sharding, dtype, b=B, h=H, hkv=HKV, d=D):
+    q = jax.ShapeDtypeStruct((b, S, h, d), dtype, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, S, hkv, d), dtype, sharding=sharding)
     nv = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
     return q, kv, kv, nv
 
@@ -61,14 +64,24 @@ def test_ragged_forward_compiles(one_chip, dtype):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_forward_and_backward_compile(one_chip, dtype):
+# (dtype, rows, q heads, kv heads, head_dim)
+FWD_BWD_CASES = [
+    pytest.param(jnp.float32, B, H, HKV, D, id="float32"),
+    pytest.param(jnp.bfloat16, B, H, HKV, D, id="bfloat16"),
+    pytest.param(jnp.float32, 7, 32, 32, 96, id="phi3v-cell-float32"),
+    pytest.param(jnp.float32, 6, 32, 4, 128, id="yi9b-cell-float32"),
+]
+
+
+@pytest.mark.parametrize("dtype, b, h, hkv, d", FWD_BWD_CASES)
+def test_forward_and_backward_compile(one_chip, dtype, b, h, hkv, d):
     def grads(q, k, v, nv):
         def loss(q_, k_, v_):
             out = attention(q_, k_, v_, num_valid=nv)
             return out.astype(jnp.float32).sum()
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    compiled = jax.jit(grads).lower(*_inputs(one_chip, dtype)).compile()
+    compiled = jax.jit(grads).lower(
+        *_inputs(one_chip, dtype, b, h, hkv, d)).compile()
     # the forward, the dq kernel and the dk/dv kernel
     assert compiled.as_text().count("tpu_custom_call") == 3
