@@ -60,14 +60,12 @@ def main(argv=None) -> int:
             plant.undo()
         key = (seed, json.dumps(plan))
         if key not in refs:
-            refs[key] = Reference(spec["config"], spec["traffic"],
-                                  seed).run(plan)
+            refs[key] = Reference(spec, seed).run(plan)
         row = {"kind": kind, "seed": seed, "plan": plan,
                "gaps": compare(prog, refs[key]),
                "seconds": time.perf_counter() - t}
         if kind == "sound" and seed in args.control_seeds:
-            control = Reference(spec["config"], spec["traffic"], seed,
-                                dtype="bfloat16").run(plan)
+            control = Reference(spec, seed, dtype="bfloat16").run(plan)
             yield {"kind": "control", "seed": seed, "plan": plan,
                    "gaps": compare(control, refs[key])}
         yield row

@@ -21,6 +21,18 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
+def param_structs(arch, conf: dict, sharding=None):
+    """The architecture module's parameter tree as float32 shapes, the
+    tree ``make_params`` makes, without making it."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=sharding),
+        arch.param_shapes(conf), is_leaf=lambda x: isinstance(x, tuple))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -35,25 +47,23 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench import harness, program
-    from bench.inputs import param_shapes
     from repro.api import lm_workload
 
     jax.config.update("jax_enable_compilation_cache", False)
     spec = harness.load_cell(ROOT, args.workload)
-    conf, traffic = dict(spec["config"]), spec["traffic"]
+    conf, traffic, arch = dict(spec["config"]), spec["traffic"], spec["arch"]
     if args.layers is not None:
         conf["num_hidden_layers"] = args.layers
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
     sh = SingleDeviceSharding(topo.devices[0])
-    wl = lm_workload(program.program_config(conf),
-                     program._FeedSource(None), use_kernel=True)
+    wl = lm_workload(arch.program_config(conf), program._FeedSource(None),
+                     use_kernel=True)
 
     def struct(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    params = jax.tree.map(struct, param_shapes(conf),
-                          is_leaf=lambda x: isinstance(x, tuple))
+    params = param_structs(arch, conf, sh)
     n, s = max(harness.reachable_batches(traffic)), traffic["seq_len"]
     data = {"tokens": struct((n, s), jnp.int32),
             "targets": struct((n, s), jnp.int32)}

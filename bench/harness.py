@@ -1,12 +1,15 @@
 """One run of one benchmark cell.
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
-the cell's configuration (``bench/configs/<config>.json``), its traffic
-(``bench/traffic/<traffic>.json``), the limits of its correctness check
-(``bench/limits/<cell>.json``) and one reader per metric
-(``bench/metrics/<metric>.py``, a ``read(run)`` that returns the value or
-``None`` where the run has nothing to read).  Adding a configuration, a
-traffic mix or a metric adds files; no file here changes.
+the cell's configuration (``bench/configs/<config>.json``), the
+architecture module its ``"model"`` key names (``bench/arch/<model>.py``:
+weights, reference forward pass, work counts and the mapping to the
+program's ModelConfig), its traffic (``bench/traffic/<traffic>.json``),
+the limits of its correctness check (``bench/limits/<cell>.json``) and one
+reader per metric (``bench/metrics/<metric>.py``, a ``read(run)`` that
+returns the value or ``None`` where the run has nothing to read).  Adding
+an architecture, a configuration, a traffic mix or a metric adds files;
+no file here changes.
 
 A run: set-up (weights from the seed on the device, the trainer built
 through ``repro.api`` with its probe round, every bucket the workers can
@@ -23,6 +26,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import gzip
 import importlib.util
@@ -69,13 +73,15 @@ def load_cell(root: Path, name: str) -> dict:
     cell = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     entry = configs[cell["config"]]
+    conf = read_json(root / entry["file"])
 
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
 
     return {
         "cell": cell,
-        "config": read_json(root / entry["file"]),
+        "config": conf,
+        "arch": load_arch(root, conf["model"]),
         "traffic": read_json(root / "bench" / "traffic"
                              / f"{cell['traffic']}.json"),
         "limits": read_json(root / "bench" / "limits" / f"{name}.json"),
@@ -84,14 +90,26 @@ def load_cell(root: Path, name: str) -> dict:
     }
 
 
-def load_reader(root: Path, metric: str):
-    """``read`` of ``bench/metrics/<metric>.py``."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
+def _load_module(path: Path, prefix: str):
+    name = path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(f"{prefix}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(root: Path, metric: str):
+    """``read`` of ``bench/metrics/<metric>.py``."""
+    return _load_module(root / "bench" / "metrics" / f"{metric}.py",
+                        "bench_metric").read
+
+
+@functools.lru_cache(maxsize=None)
+def load_arch(root: Path, model: str):
+    """The architecture module ``bench/arch/<model>.py``, loaded once per
+    path, so that what is cached by module (the jitted weight maker) is
+    found again by every run of a process."""
+    return _load_module(root / "bench" / "arch" / f"{model}.py", "bench_arch")
 
 
 # ------------------------------------------------------------ measuring
@@ -150,6 +168,7 @@ class Run:
     """What the metric readers read."""
 
     conf: dict
+    arch: object           # the configuration's bench/arch module
     traffic: dict
     chips: int
     device_kind: str
@@ -217,7 +236,7 @@ def check_chips(chips: int, require_tpu: bool) -> list:
     return devices[:chips]
 
 
-def checked_steps(session, feed: Feed, conf: dict, seed: int, n: int,
+def checked_steps(session, feed: Feed, arch, conf: dict, seed: int, n: int,
                   b1: float) -> tuple[dict, list]:
     """The first ``n`` steps, through ``Session.step`` on the rows the
     feed logs: each step's loss, the first combined gradient's leaf norms
@@ -234,7 +253,7 @@ def checked_steps(session, feed: Feed, conf: dict, seed: int, n: int,
         if i == 0:
             grad_norms = {k: v / (1 - b1) for k, v in
                           leaf_norms(program.first_moment(trainer)).items()}
-    p0 = jax.device_put(make_params(conf, seed),
+    p0 = jax.device_put(make_params(arch, conf, seed),
                         jax.tree.map(lambda a: a.sharding, trainer.params))
     delta = leaf_norms(jax.tree.map(jnp.subtract, trainer.params, p0))
     del p0
@@ -293,6 +312,7 @@ def set_up(root: Path, name: str, seed: int, *, require_tpu: bool = True,
     compile cache stays off."""
     spec = load_cell(root, name)
     cell, conf, traffic = spec["cell"], spec["config"], spec["traffic"]
+    arch = spec["arch"]
     devices = check_chips(cell["chips"], require_tpu)
     cache = None
     if require_tpu:
@@ -306,13 +326,14 @@ def set_up(root: Path, name: str, seed: int, *, require_tpu: bool = True,
     spans = Spans()
     feed = Feed(conf, traffic["seq_len"], seed)
     session = program.build_session(
-        conf, traffic, seed, make_params(conf, seed),
+        arch.program_config(conf), traffic, seed,
+        make_params(arch, conf, seed),
         spans.wrap("fetch", feed.next_batch),
         observe=lambda fn: spans.wrap("observe", fn), plant=plant)
     trainer = session.trainer
     warmed = warm_buckets(trainer, feed, traffic)
     traces0 = program.traces(trainer)
-    prog, plan = checked_steps(session, feed, conf, seed,
+    prog, plan = checked_steps(session, feed, arch, conf, seed,
                                traffic["checked_steps"],
                                traffic["optimizer"]["b1"])
     traces = program.traces(trainer) - traces0
@@ -367,9 +388,10 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
     log("memory", **max((d.memory_stats() or {} for d in used),
                         key=lambda m: m.get("peak_bytes_in_use", 0)))
 
-    run = Run(conf=conf, traffic=traffic, chips=cell["chips"],
-              device_kind=devices[0].device_kind, setup_s=setup_s,
-              window_s=window_s, rounds=rounds, peak_bytes=peak)
+    run = Run(conf=conf, arch=spec["arch"], traffic=traffic,
+              chips=cell["chips"], device_kind=devices[0].device_kind,
+              setup_s=setup_s, window_s=window_s, rounds=rounds,
+              peak_bytes=peak)
     prog, plan = su.prog, su.plan
     del su, session, trainer
     gc.collect()          # the trainer's jitted closures hold it in a cycle
@@ -419,7 +441,7 @@ def reference_gaps(spec: dict, seed: int, plan: list, prog: dict) -> dict:
     """The numbers ``compare`` gives for the checked steps against the
     plain reference."""
     t = time.perf_counter()
-    ref = Reference(spec["config"], spec["traffic"], seed).run(plan)
+    ref = Reference(spec, seed).run(plan)
     log("reference", seconds=time.perf_counter() - t,
         losses=ref["losses"], program_losses=prog["losses"])
     return compare(prog, ref)

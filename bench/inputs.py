@@ -2,17 +2,18 @@
 weights and the rows.  The reference makes them again with the same code,
 so it takes nothing that the program made.
 
-Weights are made on the device in one jitted call, in float32, as a tree
-with the program's parameter layout (a decoder's layers stacked along a
-leading axis).  Rows are indexed by (worker, call, row): row r of a
-worker's i-th fetch is the same whatever batch size the fetch asked for,
-so a resized or padded batch changes only how many rows are read.
+Weights are made on the device in one jitted call, in float32, as the
+tree of shapes the configuration's architecture module gives
+(``bench/arch/<model>.py``), each leaf by that module's ``init_leaf``.
+Rows are indexed by (worker, call, row): row r of a worker's i-th fetch
+is the same whatever batch size the fetch asked for, so a resized or
+padded batch changes only how many rows are read.
 """
 
 from __future__ import annotations
 
 import functools
-import math
+import json
 
 import jax
 import jax.numpy as jnp
@@ -28,43 +29,8 @@ def base_key(seed: int):
 # ------------------------------------------------------------------ weights
 
 
-def param_shapes(conf: dict) -> dict:
-    """Parameter tree of shapes: embedding, stacked layers, final norm and
-    output head (the program's ``init_lm`` layout)."""
-    d, ff, v = conf["hidden_size"], conf["intermediate_size"], \
-        conf["vocab_size"]
-    h, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    dh = conf.get("head_dim") or d // h
-    n = conf["num_hidden_layers"]
-    return {
-        "embed": {"table": (v, d)},
-        "groups": {"b0": {
-            "norm1": {"scale": (n, d)},
-            "attn": {"wq": {"w": (n, d, h * dh)},
-                     "wk": {"w": (n, d, hkv * dh)},
-                     "wv": {"w": (n, d, hkv * dh)},
-                     "wo": {"w": (n, h * dh, d)}},
-            "norm2": {"scale": (n, d)},
-            "mlp": {"w_gate": {"w": (n, d, ff)},
-                    "w_up": {"w": (n, d, ff)},
-                    "w_down": {"w": (n, ff, d)}},
-        }},
-        "final_norm": {"scale": (d,)},
-        "lm_head": {"w": (d, v)},
-    }
-
-
 def _is_shape(x) -> bool:
     return isinstance(x, tuple)
-
-
-def _init_leaf(path: str, key, shape):
-    """RMSNorm scales 1, the embedding N(0, 1), matmul weights
-    N(0, 1/fan_in)."""
-    if "norm" in path:
-        return jnp.ones(shape, jnp.float32)
-    std = 1.0 if path.startswith("embed") else 1.0 / math.sqrt(shape[-2])
-    return std * jax.random.normal(key, shape, jnp.float32)
 
 
 def _leaf_paths(shapes: dict) -> list[str]:
@@ -74,24 +40,25 @@ def _leaf_paths(shapes: dict) -> list[str]:
 
 
 @functools.lru_cache(maxsize=None)
-def _params_fn(conf_items):
-    shapes = param_shapes(dict(conf_items))
+def _params_fn(arch, conf_json: str):
+    shapes = arch.param_shapes(json.loads(conf_json))
     leaves, tree = jax.tree.flatten(shapes, is_leaf=_is_shape)
     paths = _leaf_paths(shapes)
 
     def make(key):
         return jax.tree.unflatten(tree, [
-            _init_leaf(path, jax.random.fold_in(key, i), shape)
+            arch.init_leaf(path, jax.random.fold_in(key, i), shape)
             for i, (path, shape) in enumerate(zip(paths, leaves))])
 
     return jax.jit(make)
 
 
-def make_params(conf: dict, seed: int):
-    """The weights of a run, on the default device, in one jitted call."""
-    items = tuple(sorted((k, v) for k, v in conf.items()
-                         if isinstance(v, (int, float, str))))
-    return _params_fn(items)(base_key(seed))
+def make_params(arch, conf: dict, seed: int):
+    """The weights of a run, on the default device, in one jitted call:
+    ``arch.init_leaf`` for each leaf of ``arch.param_shapes(conf)``, keyed
+    by the leaf's place in the tree."""
+    return _params_fn(arch, json.dumps(conf, sort_keys=True))(
+        base_key(seed))
 
 
 # --------------------------------------------------------------------- rows

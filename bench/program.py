@@ -3,12 +3,14 @@
 Everything the benchmark asks of the trainer beyond ``repro.api``'s public
 surface (``Experiment``, ``Session.step``, ``Session.batches``,
 ``MeshTrainer.bucket_for``, ``StepRecord``) sits here: building the
-session from a cell's files, running a worker's compiled step at a bucket
-without a round (``warm``), Adam's first moment (``first_moment``), the
-trainer's trace counter (``traces``), the chips it holds (``devices``) and
-the two places the planted faults break (``replace_update``,
-``patch_combine``).  A program change that moves one of these edits this
-file alone; PERF.md lists the public hooks that would let it go.
+session from a cell's traffic file and the ModelConfig that its
+architecture module maps the configuration to, running a worker's
+compiled step at a bucket without a round (``warm``), Adam's first
+moment (``first_moment``), the trainer's trace counter (``traces``), the
+chips it holds (``devices``) and the two places the planted faults break
+(``replace_update``, ``patch_combine``).  A program change that moves
+one of these edits this file alone; PERF.md lists the public hooks that
+would let it go.
 """
 
 from __future__ import annotations
@@ -18,28 +20,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def program_config(conf: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs import get_config
-
-    cfg = get_config(conf["program"]["arch"])
-    if cfg.family != conf["program"]["family"]:
-        raise ValueError(f"{conf['name']}: the program's {cfg.name} is "
-                         f"{cfg.family}, the file says "
-                         f"{conf['program']['family']}")
-    return cfg.with_(
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        vocab_size=conf["vocab_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim")
-        or conf["hidden_size"] // conf["num_attention_heads"],
-        d_ff=conf["intermediate_size"], rope_theta=conf["rope_theta"],
-        norm_eps=conf["rms_norm_eps"],
-        num_patches=int(conf.get("num_image_tokens", 0)),
-        tie_embeddings=conf["tie_word_embeddings"])
 
 
 class _FeedSource:
@@ -55,13 +35,14 @@ class _FeedSource:
         pass
 
 
-def build_session(conf: dict, traffic: dict, seed: int, params, next_batch,
+def build_session(model, traffic: dict, seed: int, params, next_batch,
                   observe=None, plant=None):
-    """The trainer behind ``repro.api``, built with the run's weights and
-    its rows from ``next_batch(worker, n)``, as the cell's traffic file
-    describes it.  ``observe`` wraps the controller's ``observe``;
-    ``plant`` (tests and the control runs only, ``bench/faults.py``)
-    breaks the timed path."""
+    """The trainer behind ``repro.api`` for the program's ModelConfig
+    ``model`` (the architecture module's ``program_config``), built with
+    the run's weights and its rows from ``next_batch(worker, n)``, as the
+    cell's traffic file describes it.  ``observe`` wraps the controller's
+    ``observe``; ``plant`` (tests and the control runs only,
+    ``bench/faults.py``) breaks the timed path."""
     from repro.api import (ClusterSpec, Experiment, MeshBackend,
                            TrainConfig, lm_workload)
     from repro.core import ControllerConfig
@@ -69,8 +50,7 @@ def build_session(conf: dict, traffic: dict, seed: int, params, next_batch,
     from repro.optim import adam
 
     workload = dataclasses.replace(
-        lm_workload(program_config(conf), _FeedSource(next_batch),
-                    use_kernel=True),
+        lm_workload(model, _FeedSource(next_batch), use_kernel=True),
         init=lambda key: params)
     if plant is not None:
         workload = plant.workload(workload)

@@ -3,13 +3,14 @@ a run's ``correct``.
 
 The reference imports nothing of the program.  It takes the weights and
 rows from ``bench/inputs.py`` (made again from the seed), and follows the
-configuration in straightforward ``jax.numpy``: embedding lookup (the
-image prefix overwriting the first positions), RMSNorm, rope on
-interleaved pairs, causal softmax attention with grouped KV heads, SwiGLU,
-output head, cross-entropy over the positions after the prefix.  Each
-worker's gradient is the mean over its valid rows' positions; the workers'
-gradients are combined with weights b_k / sum(b); Adam updates the
-parameters.  In float32 every matmul runs at ``highest`` precision.
+configuration in straightforward ``jax.numpy``: the forward pass and loss
+are the ``row_losses`` of the configuration's architecture module
+(``bench/arch/<model>.py``), built from the helpers here (RMSNorm, rope
+on interleaved pairs, causal softmax attention with grouped KV heads and
+an optional window).  Each worker's gradient is the mean over its valid
+rows' positions; the workers' gradients are combined with weights
+b_k / sum(b); Adam updates the parameters.  In float32 every matmul runs
+at ``highest`` precision.
 
 It runs in blocks of rows, one row per device at a time, with attention
 in blocks of queries, so that it fits beside nothing else on the chip.
@@ -34,7 +35,7 @@ from bench.inputs import Feed, make_params
 Q_BLOCK = 512
 
 
-# -------------------------------------------------------------------- model
+# ------------------------------------------------- helpers of the models
 
 
 def rms_norm(x, scale, eps):
@@ -56,9 +57,10 @@ def rope(x, theta):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def causal_attention(q, k, v):
+def causal_attention(q, k, v, window=None):
     """q: (R, S, H, dh), k, v: (R, S, Hkv, dh); softmax in float32, one
-    block of queries at a time (recomputed in the backward pass)."""
+    block of queries at a time (recomputed in the backward pass).  With
+    ``window``, query q sees key k only where q - k < window."""
     r, s, h, dh = q.shape
     rep = h // k.shape[2]
     k = jnp.repeat(k, rep, axis=2)
@@ -71,6 +73,8 @@ def causal_attention(q, k, v):
         logits = logits / math.sqrt(dh)
         qpos = start + jnp.arange(qb.shape[1])
         keep = jnp.arange(s)[None, :] <= qpos[:, None]
+        if window is not None:
+            keep &= jnp.arange(s)[None, :] > qpos[:, None] - window
         logits = jnp.where(keep, logits, -jnp.inf)
         probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
         return jnp.einsum("rhqk,rkhd->rqhd", probs, v,
@@ -81,49 +85,18 @@ def causal_attention(q, k, v):
                             for i in range(0, s, qb)], axis=1)
 
 
-def row_losses(params, conf, tokens, targets, prefix, row_w, dtype):
-    """(sum of position losses times row weights, sum of their weights)."""
-    d = conf["hidden_size"]
-    h, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    dh = conf.get("head_dim") or d // h
-    eps, theta = conf["rms_norm_eps"], conf["rope_theta"]
-    p = jax.tree.map(lambda a: a.astype(dtype), params)
-    r, s = tokens.shape
-    x = p["embed"]["table"][tokens]
-    n_prefix = 0 if prefix is None else prefix.shape[1]
-    if n_prefix:
-        x = jnp.concatenate([prefix.astype(dtype), x[:, n_prefix:]], axis=1)
-    layers = p["groups"]["b0"]
-    for i in range(conf["num_hidden_layers"]):
-        lp = jax.tree.map(lambda a: a[i], layers)
-        y = rms_norm(x, lp["norm1"]["scale"], eps)
-        q = (y @ lp["attn"]["wq"]["w"]).reshape(r, s, h, dh)
-        k = (y @ lp["attn"]["wk"]["w"]).reshape(r, s, hkv, dh)
-        v = (y @ lp["attn"]["wv"]["w"]).reshape(r, s, hkv, dh)
-        o = causal_attention(rope(q, theta), rope(k, theta), v)
-        x = x + o.reshape(r, s, h * dh) @ lp["attn"]["wo"]["w"]
-        y = rms_norm(x, lp["norm2"]["scale"], eps)
-        m = lp["mlp"]
-        x = x + (jax.nn.silu(y @ m["w_gate"]["w"]) * (y @ m["w_up"]["w"])
-                 ) @ m["w_down"]["w"]
-    x = rms_norm(x, p["final_norm"]["scale"], eps)
-    logits = (x @ p["lm_head"]["w"]).astype(jnp.float32)
-    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-        logits, targets[..., None], axis=-1)[..., 0]
-    pos_w = (jnp.arange(s) >= n_prefix).astype(jnp.float32)
-    w = row_w[:, None] * pos_w[None, :]
-    return jnp.sum(nll * w), jnp.sum(w)
-
-
 # --------------------------------------------------------------- the steps
 
 
 class Reference:
     """Training steps of the plain model on the rows a run logged."""
 
-    def __init__(self, conf: dict, traffic: dict, seed: int,
-                 dtype: str = "float32", devices=None):
-        self.conf, self.seed = conf, seed
+    def __init__(self, spec: dict, seed: int, dtype: str = "float32",
+                 devices=None):
+        """``spec``: a cell as ``harness.load_cell`` reads it (its
+        configuration, traffic and architecture module)."""
+        conf, traffic = spec["config"], spec["traffic"]
+        self.arch, self.conf, self.seed = spec["arch"], conf, seed
         self.dtype = jnp.dtype(dtype)
         self.opt = traffic["optimizer"]
         self.feed = Feed(conf, traffic["seq_len"], seed)
@@ -137,8 +110,9 @@ class Reference:
         self._adam = jax.jit(self._adam_update, out_shardings=self.replicated)
 
     def _loss(self, params, tokens, targets, prefix, row_w):
-        loss, count = row_losses(params, self.conf, tokens, targets, prefix,
-                                 row_w, self.dtype)
+        loss, count = self.arch.row_losses(params, self.conf, tokens,
+                                           targets, prefix, row_w,
+                                           self.dtype)
         return loss, count
 
     def _precision(self):
@@ -186,8 +160,8 @@ class Reference:
 
         Returns the loss of each step, the leaf norms of the first step's
         combined gradient and of the parameters' change over all steps."""
-        params0 = jax.device_put(make_params(self.conf, self.seed),
-                                 self.replicated)
+        params0 = jax.device_put(
+            make_params(self.arch, self.conf, self.seed), self.replicated)
         params = jax.tree.map(lambda a: a.astype(self.dtype), params0)
         m = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), params0)
         v = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), params0)

@@ -10,8 +10,9 @@ plain dict (what ``bench/testdata`` holds):
 ``devices`` holds each accelerator plane's op events (its "XLA Ops"
 line); ``spans`` the benchmark's own host spans (names ``bench.*``), on
 the same clock; ``window`` is the span ``bench.window``.  ``reduce``
-turns that into busy time, kernel time, collective time, the ops that
-took most time and the idle gaps by what the host was doing.
+turns that into busy time, each Pallas kernel's time by name (over the
+window and per round), collective time, the ops that took most time and
+the idle gaps by what the host was doing.
 """
 
 from __future__ import annotations
@@ -23,10 +24,11 @@ import re
 
 # An op event is named by its HLO instruction text,
 #   %attention.6 = (f32[...], ...) custom-call(...), custom_call_target=...
-# The flash-attention kernels (forward, and the backward's dq and dk/dv)
-# are the Pallas custom calls of the jitted ``attention`` wrapper.
-KERNEL = re.compile(r'^%?attention(\.\d+)? = .*custom_call_target='
-                    r'"tpu_custom_call"')
+# Every Pallas kernel is such a custom call; its time is kept under the
+# stem of its op name (``kernel_stem``): the flash-attention kernels
+# (forward, and the backward's dq and dk/dv) are the calls of the jitted
+# ``attention`` wrapper, stem ``attention``.
+KERNEL = re.compile(r'^%?[\w.-]+ = .*custom_call_target="tpu_custom_call"')
 # The collectives, by their HLO opcode (an all-reduce inside a shard_map
 # is named after its ``psum``): the exchange inside one program's chips.
 # copy-start and copy-done move data between memories of one chip.
@@ -127,13 +129,13 @@ class TraceSummary:
     window_s: float
     chips: int
     busy_s: float            # mean over the chips
-    kernel_s: float          # summed over the chips
-    kernel_events: int
+    kernel_s: dict           # kernel stem -> seconds, summed over the chips
+    kernel_events: dict      # kernel stem -> events
     collective_s: list[float]  # per chip
     op_s: dict               # op name -> seconds, mean over the chips
     idle_s: dict             # host span -> idle seconds, mean over chips
-    # per ``bench.round`` span of the window, in order: the kernels'
-    # seconds inside it summed over the chips, or None where the span is
+    # per ``bench.round`` span of the window, in order: {kernel stem:
+    # seconds inside it summed over the chips}, or None where the span is
     # not wholly inside the covered part
     round_kernel_s: list = dataclasses.field(default_factory=list)
 
@@ -162,8 +164,8 @@ def reduce(trace: dict, planes=None) -> TraceSummary:
     names = sorted(k for k, v in trace["devices"].items() if v) \
         if planes is None else list(planes)
     lo, hi = covered(trace, names)
-    busy_total = kernel = 0.0
-    kernel_events = 0
+    busy_total = 0.0
+    kernel, kernel_events = {}, {}
     collective, op_s, idle_s = [], {}, {}
     for name in names:
         events = trace["devices"][name]
@@ -177,8 +179,9 @@ def reduce(trace: dict, planes=None) -> TraceSummary:
             name = op_name(op)
             op_s[name] = op_s.get(name, 0.0) + inside
             if KERNEL.search(op):
-                kernel += inside
-                kernel_events += 1
+                stem = kernel_stem(op)
+                kernel[stem] = kernel.get(stem, 0) + inside
+                kernel_events[stem] = kernel_events.get(stem, 0) + 1
             if COLLECTIVE.search(op):
                 coll += inside
         collective.append(coll / 1e9)
@@ -187,19 +190,22 @@ def reduce(trace: dict, planes=None) -> TraceSummary:
             idle_s[label] = idle_s.get(label, 0.0) + (b - a)
     rounds = [(s_, s_ + d) for name, s_, d in trace["spans"]
               if name == ROUND and trace["window"][0] <= s_]
+    kernels = [(kernel_stem(e[0]), e) for name in names
+               for e in trace["devices"][name] if KERNEL.search(e[0])]
     round_kernel_s = []
     for a, b in rounds:
         if not (lo <= a and b <= hi):
             round_kernel_s.append(None)
             continue
-        round_kernel_s.append(sum(
-            sum(y - x for x, y in clip([e], a, b))
-            for name in names for e in trace["devices"][name]
-            if KERNEL.search(e[0])) / 1e9)
+        ns = {}
+        for stem, e in kernels:
+            ns[stem] = ns.get(stem, 0) + sum(y - x for x, y in clip([e], a, b))
+        round_kernel_s.append({k: v / 1e9 for k, v in ns.items()})
     n = max(len(names), 1)
     return TraceSummary(
         window_s=(hi - lo) / 1e9, chips=len(names),
-        busy_s=busy_total / n / 1e9, kernel_s=kernel / 1e9,
+        busy_s=busy_total / n / 1e9,
+        kernel_s={k: v / 1e9 for k, v in kernel.items()},
         kernel_events=kernel_events, collective_s=collective,
         op_s={k: v / n / 1e9 for k, v in op_s.items()},
         idle_s={k: v / n / 1e9 for k, v in idle_s.items()},
@@ -209,6 +215,12 @@ def reduce(trace: dict, planes=None) -> TraceSummary:
 def op_name(event: str) -> str:
     """``attention.6`` of ``%attention.6 = (f32[...]) custom-call(...)``."""
     return event.split(" = ", 1)[0].lstrip("%")
+
+
+def kernel_stem(event: str) -> str:
+    """``attention`` of ``%attention.6 = ...``: the op name without the
+    number XLA appends to each instance."""
+    return op_name(event).split(".", 1)[0]
 
 
 def breakdown(summary: TraceSummary, top: int = 10) -> dict:

@@ -13,7 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 TINY_DENSE = {
-    "name": "tiny-dense", "source": "test",
+    "name": "tiny-dense", "source": "test", "model": "decoder",
     "hidden_size": 64, "intermediate_size": 128,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "num_hidden_layers": 1, "vocab_size": 256, "rope_theta": 10000.0,

@@ -1,6 +1,7 @@
-"""The benchmark harness on the CPU: cells, traffic and metrics found by
-name from files added beside the others, a tiny cell's whole run through
-the harness, and the entry's refusal to run without a TPU."""
+"""The benchmark harness on the CPU: cells, traffic, metrics and
+architectures found by name from files added beside the others, a tiny
+cell's whole run through the harness, and the entry's refusal to run
+without a TPU."""
 
 from __future__ import annotations
 
@@ -45,7 +46,8 @@ def test_added_files_are_found_by_name(tmp_path):
         m["name"] for m in harness.load_cell(root, "tiny.vlm")["per_layer"]}
     # cell-specific metrics keep to their cells
     assert "collective_share" not in {m["name"] for m in cell["per_layer"]}
-    run = harness.Run(conf=cell["config"], traffic=cell["traffic"], chips=1,
+    run = harness.Run(conf=cell["config"], arch=cell["arch"],
+                      traffic=cell["traffic"], chips=1,
                       device_kind="TPU v5 lite", setup_s=1.0, window_s=2.0,
                       rounds=[harness.Round([1, 2, 3], [1, 2, 3], [], 0, 1),
                               harness.Round([2, 2, 2], [2, 2, 2], [], 0, 1)],
@@ -63,6 +65,10 @@ def test_every_metric_has_a_reader():
         assert callable(harness.load_reader(ROOT, m["name"])), m["name"]
     for w in spec["workloads"]:
         cell = harness.load_cell(ROOT, w["name"])
+        for fn in ("param_shapes", "init_leaf", "row_losses",
+                   "program_config", "param_count",
+                   "train_flops_per_position", "kernel_work"):
+            assert callable(getattr(cell["arch"], fn)), (w["name"], fn)
         assert set(cell["limits"]["limits"]) == {
             "loss_gap.1", "loss_gap.2", "loss_gap.3", "grad_gap",
             "delta_gap"}
@@ -138,3 +144,123 @@ def test_benchmark_files_alone_do_not_run(tmp_path):
         text=True, timeout=300)
     assert proc.returncode != 0
     assert '"correct"' not in proc.stdout
+
+
+# An architecture that the benchmark does not have: the dense decoder with
+# every layer's attention windowed.  Written into a benchmark root as a new
+# file, beside a configuration that names it; the window reaches the
+# program through ``program_config`` and the reference through the shared
+# helpers, and the work counts keep only the pairs inside the window.
+TINY_WINDOW_MODULE = '''
+"""The dense decoder with every layer's attention windowed to
+``sliding_window`` positions."""
+
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+
+from bench import work
+from bench.harness import load_arch
+from bench.reference import causal_attention, rms_norm, rope
+
+decoder = load_arch(Path(__file__).resolve().parents[2], "decoder")
+param_shapes = decoder.param_shapes
+init_leaf = decoder.init_leaf
+param_count = decoder.param_count
+
+
+def _dims(conf):
+    h = conf["num_attention_heads"]
+    return (h, conf["num_key_value_heads"], conf["head_dim"],
+            conf["sliding_window"])
+
+
+def row_losses(params, conf, tokens, targets, prefix, row_w, dtype):
+    h, hkv, dh, window = _dims(conf)
+    eps, theta = conf["rms_norm_eps"], conf["rope_theta"]
+    p = jax.tree.map(lambda a: a.astype(dtype), params)
+    r, s = tokens.shape
+    x = p["embed"]["table"][tokens]
+    layers = p["groups"]["b0"]
+    for i in range(conf["num_hidden_layers"]):
+        lp = jax.tree.map(lambda a: a[i], layers)
+        y = rms_norm(x, lp["norm1"]["scale"], eps)
+        q = (y @ lp["attn"]["wq"]["w"]).reshape(r, s, h, dh)
+        k = (y @ lp["attn"]["wk"]["w"]).reshape(r, s, hkv, dh)
+        v = (y @ lp["attn"]["wv"]["w"]).reshape(r, s, hkv, dh)
+        o = causal_attention(rope(q, theta), rope(k, theta), v, window)
+        x = x + o.reshape(r, s, h * dh) @ lp["attn"]["wo"]["w"]
+        y = rms_norm(x, lp["norm2"]["scale"], eps)
+        m = lp["mlp"]
+        x = x + (jax.nn.silu(y @ m["w_gate"]["w"]) * (y @ m["w_up"]["w"])
+                 ) @ m["w_down"]["w"]
+    x = rms_norm(x, p["final_norm"]["scale"], eps)
+    logits = (x @ p["lm_head"]["w"]).astype(jnp.float32)
+    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+        logits, targets[..., None], axis=-1)[..., 0]
+    w = jnp.broadcast_to(row_w[:, None], nll.shape)
+    return jnp.sum(nll * w), jnp.sum(w)
+
+
+def program_config(conf):
+    return decoder.program_config(conf).with_(window=conf["sliding_window"])
+
+
+def train_flops_per_position(conf, seq):
+    h, _, dh, window = _dims(conf)
+    attn = (conf["num_hidden_layers"] * 12 * dh * h
+            * work.attention_pairs(seq, window))
+    return 6 * decoder.matmul_params(conf) + attn / seq
+
+
+def kernel_work(conf, seq, valid_rows):
+    h, hkv, dh, window = _dims(conf)
+    rows = valid_rows * conf["num_hidden_layers"]
+    return {"attention": work.flash_work(seq, h, hkv, dh, rows, window)}
+'''
+TINY_WINDOW = dict(_tiny.TINY_DENSE, name="tiny-window", model="tiny_window",
+                   sliding_window=48)
+
+
+def test_an_architecture_is_added_as_files(tmp_path):
+    """A windowed architecture, its configuration, traffic and limits
+    added to a root as new files run through the harness to ``correct``,
+    with no file that the benchmark has changed; the window reaches the
+    program, the reference and the work counts."""
+    root = tiny_root(tmp_path)
+    (root / "bench" / "arch" / "tiny_window.py").write_text(
+        TINY_WINDOW_MODULE)
+    write_json(root / "bench" / "configs" / "tiny-window.json", TINY_WINDOW)
+    write_json(root / "bench" / "limits" / "tiny.window.json",
+               {"limits": _tiny.TINY_LIMITS})
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({
+        "name": "tiny-window", "source": "test",
+        "file": "bench/configs/tiny-window.json", "reduced": [],
+        "why": "test"})
+    spec["workloads"].append({"name": "tiny.window", "config": "tiny-window",
+                              "traffic": "tiny-het3", "chips": 1,
+                              "why": "test"})
+    write_json(root / "BENCHMARK.json", spec)
+    cmp = filecmp.dircmp(ROOT / "bench", root / "bench",
+                         ignore=["__pycache__"])
+    assert not cmp.diff_files and not cmp.left_only
+    assert not any(sub.diff_files or sub.left_only
+                   for sub in cmp.subdirs.values())
+
+    cell = harness.load_cell(root, "tiny.window")
+    arch, conf = cell["arch"], cell["config"]
+    assert arch.program_config(conf).window == 48
+    seq = cell["traffic"]["seq_len"]
+    decoder = harness.load_arch(root, "decoder")
+    assert arch.kernel_work(conf, seq, 1)["attention"][0] < \
+        decoder.kernel_work(conf, seq, 1)["attention"][0]
+    assert arch.train_flops_per_position(conf, seq) < \
+        decoder.train_flops_per_position(conf, seq)
+
+    out = harness.run_cell(root, "tiny.window", 2**31 + 4321, 0.3, False,
+                           t_start=0.0, require_tpu=False)
+    assert out["correct"] is True, out["checks"]
+    assert out["failed"] == 0 and out["attempted"] >= 1
+    assert out["window_compiles"] == {"compiles": 0, "traces": 0}

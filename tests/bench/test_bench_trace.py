@@ -1,8 +1,10 @@
 """The reduction from a profiler trace to busy, kernel, collective and idle
 time: on hand-made events, and on traces recorded on TPU v5e chips
-(``bench/testdata``): the busy/idle union against a timeline count, the
-flash kernels' events picked by name, and the ops that move data between
-chips told apart from the rest."""
+(``bench/testdata``): the busy/idle union against a timeline count, every
+Pallas kernel's events kept by the stem of its name, the ops that move
+data between chips told apart from the rest, and the readings of the
+recorded traces pinned to what the reduction gave before it kept kernels
+by stem."""
 
 from __future__ import annotations
 
@@ -48,11 +50,17 @@ def test_kernel_and_exchange_names():
               's32[] %min.6), custom_call_target="tpu_custom_call"')
     assert tr.KERNEL.search(kernel)
     assert tr.op_name(kernel) == "attention.6"
+    assert tr.kernel_stem(kernel) == "attention"
+    # any Pallas call is a kernel, kept under its own stem
+    other = ('%custom-call.3 = f32[8] custom-call(f32[8] %a), '
+             'custom_call_target="tpu_custom_call"')
+    assert tr.KERNEL.search(other) and tr.kernel_stem(other) == "custom-call"
+    assert not tr.COLLECTIVE.search(other)
     for name in ("%fusion.12 = f32[2] fusion(f32[2] %a), kind=kLoop",
                  "%copy-start = (u32[2]{0:T(128)S(1)}) copy-start(%key.1)",
                  "%copy-done = u32[2]{0} copy-done(%copy-start)",
-                 '%custom-call.3 = f32[8] custom-call(f32[8] %a), '
-                 'custom_call_target="tpu_custom_call"'):
+                 '%custom-call.4 = f32[8] custom-call(f32[8] %a), '
+                 'custom_call_target="Sharding"'):
         assert not tr.KERNEL.search(name)
         assert not tr.COLLECTIVE.search(name)
     for name in ("%all-reduce.1 = f32[8] all-reduce(f32[8] %a)",
@@ -110,8 +118,8 @@ def test_recorded_busy_is_the_union_of_op_intervals(cell, chips):
 @pytest.mark.parametrize("cell,chips", RECORDED)
 def test_recorded_kernel_events_are_the_flash_kernels(cell, chips):
     """Every Pallas call in these programs is a flash-attention kernel and
-    is picked; the other custom calls and the fusions are not; each chip
-    runs them."""
+    is picked, under the stem ``attention``; the other custom calls and
+    the fusions are not; each chip runs them."""
     trace = load(f"{cell}.trace.json.gz")
     for plane, events in trace["devices"].items():
         picked = [e for e in events if tr.KERNEL.search(e[0])]
@@ -119,10 +127,11 @@ def test_recorded_kernel_events_are_the_flash_kernels(cell, chips):
         for e in events:
             pallas = 'custom_call_target="tpu_custom_call"' in e[0]
             assert bool(tr.KERNEL.search(e[0])) == pallas, e[0][:200]
-        assert {tr.op_name(e[0]).split(".")[0] for e in picked} == \
-            {"attention"}
+        assert {tr.kernel_stem(e[0]) for e in picked} == {"attention"}
     assert any("ConcatBitcast" in e[0] for v in trace["devices"].values()
                for e in v)
+    s = tr.reduce(trace)
+    assert set(s.kernel_s) == set(s.kernel_events) == {"attention"}
 
 
 def test_recorded_exchange_is_the_two_chip_slices_all_reduce():
@@ -149,8 +158,46 @@ def test_recorded_kernel_time_is_split_by_round():
     left out (None), so work and time are counted over the same rounds."""
     trace = load("yi9b-slices211-dyn-4k.trace.json.gz")
     s = tr.reduce(trace)
-    whole = [k for k in s.round_kernel_s if k is not None]
+    whole = [k["attention"] for k in s.round_kernel_s if k is not None]
     assert whole and all(k > 0 for k in whole)
-    assert sum(whole) <= s.kernel_s + 1e-9
+    assert sum(whole) <= s.kernel_s["attention"] + 1e-9
     # one round's kernels: the same work every round, within a few percent
     assert max(whole) / min(whole) < 1.05
+
+
+# What the reduction gave for each recorded trace before it kept kernels by
+# stem (the flash kernels' seconds over the covered window and per whole
+# round, their events, and the two shares read from them): the same to
+# the last digit.
+PINNED = {
+    "phi3v-het3-dyn-4k": (1.553236438, 22, [0.738990978, 0.738983825, None],
+                          64.82760196970818, 4.162030319999999),
+    "yi9b-slices211-dyn-4k": (
+        3.747092285, 64,
+        [0.709880816, 0.709867586, 0.709849806, 0.709884547, 0.709871699,
+         None], 47.86816288355997, 21.72057461),
+    "phi3v-het3-dyn-4k.spans": (
+        1.499740784, 20, [0.738955765, 0.738985015, None],
+        64.70416001117536, 7.286283680000006),
+    "yi9b-slices211-dyn-4k.spans": (
+        3.747177072, 64,
+        [0.709881174, 0.709867365, 0.70988603, 0.709893352, 0.709897518,
+         None], 47.11227912940015, 20.462835989999995),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_recorded_readings_are_pinned(name):
+    from bench import harness
+
+    kernel_s, events, rounds, time_share, idle_share = PINNED[name]
+    s = tr.reduce(load(f"{name}.trace.json.gz"))
+    assert s.kernel_s == {"attention": kernel_s}
+    assert s.kernel_events == {"attention": events}
+    assert s.round_kernel_s == [None if r is None else {"attention": r}
+                                for r in rounds]
+    run = harness.Run(conf={}, arch=None, traffic={}, chips=s.chips,
+                      device_kind="TPU v5 lite", setup_s=1.0,
+                      window_s=s.window_s, rounds=[], peak_bytes=0, trace=s)
+    assert harness.load_reader(ROOT, "flash_time_share")(run) == time_share
+    assert harness.load_reader(ROOT, "device_idle_share")(run) == idle_share
