@@ -172,6 +172,10 @@ def lm_workload(model_cfg, pipe, *, aux_weight: float = 0.0,
     ``aux_weight`` (e.g. MoE balance loss; scaled by the weight sum so it
     stays commensurate with the SUM-convention main loss).
 
+    A model that counts (the held-expert layer's ``expert_pairs`` and
+    ``expert_pairs_max``) reports aux as ``{"aux_loss": aux, **counters}``;
+    the mesh trainer adds the counters up per round.
+
     ``use_kernel=True`` routes attention through the ragged Pallas kernel
     (``use_pallas``) and derives the kernel's ``num_valid`` from the very
     mask the trainer built when it padded the batch up the bucket ladder —
@@ -193,19 +197,23 @@ def lm_workload(model_cfg, pipe, *, aux_weight: float = 0.0,
                 ls, ws, aux = encdec_loss(p, model_cfg, batch["prefix"],
                                           batch["tokens"], batch["targets"],
                                           mask)
+                counters = {}
             else:
                 num_valid = None
                 if use_kernel:
                     row_w = mask if mask.ndim == 1 else mask.max(axis=-1)
                     num_valid = (row_w > 0).sum().astype(jnp.int32)
-                ls, ws, aux = lm_loss(p, model_cfg, batch["tokens"],
-                                      batch["targets"], mask,
-                                      prefix_embeds=batch.get("prefix"),
-                                      num_valid=num_valid)
+                ls, ws, aux, counters = lm_loss(
+                    p, model_cfg, batch["tokens"], batch["targets"], mask,
+                    prefix_embeds=batch.get("prefix"), num_valid=num_valid,
+                    counters=True)
             # the aux term is differentiated but reported separately: the
             # metas carry the plain SUM loss
             total = (ls + aux_weight * aux * jnp.maximum(ws, 1.0)
                      if aux_weight else ls)
+            if counters:
+                # the model's counters ride beside the aux loss
+                aux = {"aux_loss": aux, **counters}
             return total, (ls, ws, aux)
 
         (_, metas), g = jax.value_and_grad(lf, has_aux=True)(params)

@@ -19,6 +19,7 @@ ARCHITECTURES = [
     "llama3-8b",
     "gemma-2b",
     "deepseek-v2-236b",
+    "mellum2-12b-a2.5b",
 ]
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCHITECTURES}

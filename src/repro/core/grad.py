@@ -132,6 +132,8 @@ def accumulate_microbatch_grads(grad_fn, params, microbatches, masks):
         g_acc, l_acc, w_acc, a_acc = carry
         batch, mask = xs
         (loss_sum, w_sum, aux), grads = grad_fn(params, batch, mask)
+        if isinstance(aux, dict):        # a model's counters beside it
+            aux = aux["aux_loss"]
         g_acc = jax.tree_util.tree_map(jnp.add, g_acc, grads)
         return (g_acc, l_acc + loss_sum, w_acc + w_sum,
                 a_acc + aux * w_sum), None

@@ -11,6 +11,24 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary embedding of one layer kind: base ``theta``, and YaRN
+    scaling where ``yarn_factor`` is set (Hugging Face's
+    ``_compute_yarn_parameters``: frequencies blended between
+    interpolation and extrapolation over the correction range that
+    ``yarn_beta_fast`` and ``yarn_beta_slow`` give over
+    ``yarn_original_len`` positions; cos and sin scaled by
+    ``yarn_attention_factor``, 0.1 ln(factor) + 1 where None)."""
+
+    theta: float = 10_000.0
+    yarn_factor: Optional[float] = None
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str
@@ -23,7 +41,10 @@ class ModelConfig:
     num_kv_heads: int = 0
     head_dim: int = 0
     rope_theta: float = 10_000.0
-    window: Optional[int] = None          # sliding-window size (None = full causal)
+    # rope per layer kind ('attn' | 'local'): kinds not listed use plain
+    # rope at ``rope_theta``
+    rope_by_kind: Tuple[Tuple[str, Rope], ...] = ()
+    window: Optional[int] = None          # 'attn' layers' window (None = full causal)
     attn_chunk: Optional[int] = None      # online-softmax kv-chunk (None=dense)
     # mlp: 'swiglu' | 'geglu' | 'gelu' | 'moe' | 'none'
     mlp: str = "swiglu"
@@ -35,12 +56,16 @@ class ModelConfig:
     scale_embeddings: bool = False         # gemma-style sqrt(d_model) scaling
     logit_softcap: Optional[float] = None  # grok/gemma2-style tanh soft-capping
     attn_softcap: Optional[float] = None   # attention-logit soft-capping (grok)
-    # --- MoE (GShard-style one-hot dispatch; experts sharded over `model`)
+    # --- MoE: the router's width; experts sharded over `model` on the
+    # GShard one-hot path (``experts_held`` None, capacity drops), or the
+    # held-expert layer (the first ``experts_held`` of them computed here,
+    # no token dropped; layers.apply_held_moe)
     num_experts: int = 0
+    experts_held: Optional[int] = None
     num_shared_experts: int = 0
     moe_top_k: int = 0
     moe_d_ff: int = 0
-    moe_group_size: int = 1024             # router group size (tokens)
+    moe_group_size: int = 1024             # tokens routed at a time
     moe_capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     # --- MLA (deepseek-v2)
@@ -57,9 +82,10 @@ class ModelConfig:
     ssm_chunk: int = 64
     conv_kernel: int = 4
     # --- hybrid (recurrentgemma / griffin)
+    # --- layer kinds (hybrid and moe families): the repeating group
     block_pattern: Tuple[str, ...] = ("attn",)   # e.g. ('rec','rec','attn')
     lru_width: int = 0
-    local_window: int = 2048                     # hybrid local-attention window
+    local_window: int = 2048                     # 'local' layers' window
     # --- encoder-decoder (whisper)
     encoder_layers: int = 0
     encoder_seq: int = 0                          # e.g. 1500 audio frames
@@ -91,6 +117,14 @@ class ModelConfig:
     def ssm_nheads(self) -> int:
         return self.d_inner // self.ssm_head_dim if self.ssm_head_dim else 0
 
+    def rope_for(self, kind: str) -> Rope:
+        """The rotary embedding of layers of ``kind``."""
+        return dict(self.rope_by_kind).get(kind, Rope(self.rope_theta))
+
+    def window_for(self, kind: str) -> Optional[int]:
+        """The attention window of layers of ``kind`` (None = full)."""
+        return self.local_window if kind == "local" else self.window
+
     @property
     def q_dim(self) -> int:
         if self.attention == "mla":
@@ -106,12 +140,15 @@ class ModelConfig:
         if self.mlp == "moe" or self.num_experts:
             if self.moe_top_k < 1 or self.moe_top_k > self.num_experts:
                 raise ValueError("bad MoE top_k")
+            if self.experts_held is not None and not (
+                    1 <= self.experts_held <= self.num_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} must be 1 to the "
+                    f"router's width {self.num_experts}")
         if self.family == "ssm" and self.d_inner % self.ssm_head_dim != 0:
             raise ValueError("d_inner must be divisible by ssm_head_dim")
-        if self.family == "hybrid":
-            nl = self.num_layers
-            if not self.block_pattern:
-                raise ValueError("hybrid needs a block_pattern")
+        if self.family == "hybrid" and not self.block_pattern:
+            raise ValueError("hybrid needs a block_pattern")
 
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -119,7 +156,7 @@ class ModelConfig:
 
 def reduced(cfg: ModelConfig, **extra) -> ModelConfig:
     """A tiny CPU-runnable variant of the same family (smoke tests)."""
-    layers = len(cfg.block_pattern) if cfg.family == "hybrid" else 2
+    layers = len(cfg.block_pattern) if cfg.family in ("hybrid", "moe") else 2
     layers = max(2, layers)
     kw = dict(
         num_layers=layers,
@@ -146,6 +183,10 @@ def reduced(cfg: ModelConfig, **extra) -> ModelConfig:
                   moe_top_k=min(cfg.moe_top_k, 2),
                   moe_d_ff=min(cfg.moe_d_ff, 64) if cfg.moe_d_ff else 0,
                   num_shared_experts=min(cfg.num_shared_experts, 1))
+        if cfg.experts_held is not None:
+            kw.update(experts_held=min(cfg.experts_held, 4))
+    if "local" in cfg.block_pattern and cfg.family != "hybrid":
+        kw.update(local_window=8)
     if cfg.family == "ssm":
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=8)
     if cfg.family == "hybrid":

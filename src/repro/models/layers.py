@@ -17,7 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, Rope
 
 # --------------------------------------------------------------------- init
 
@@ -80,10 +80,45 @@ def apply_norm(p, x, cfg: ModelConfig):
 # ---------------------------------------------------------------------- RoPE
 
 
-def rope(x, positions, theta: float):
+def rope_frequencies(head_dim: int, spec: Rope):
+    """(inverse frequencies (head_dim/2,) float32, attention factor) of a
+    rotary embedding.
+
+    Plain rope: theta^(-2i/head_dim), factor 1.  YaRN (Hugging Face's
+    ``_compute_yarn_parameters``): the correction range is where
+    ``beta_fast`` and ``beta_slow`` rotations fit into the original
+    length, dim * ln(L / (2 pi beta)) / (2 ln theta), floored and ceiled;
+    over it a linear ramp blends the extrapolated frequencies (below the
+    range) into the interpolated ones (theta^(-2i/dim) / factor, above)."""
+    half = head_dim // 2
+    inv = spec.theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if spec.yarn_factor is None:
+        return inv, 1.0
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(
+            spec.yarn_original_len / (rotations * 2 * math.pi))
+            / (2 * math.log(spec.theta)))
+
+    low = max(math.floor(correction_dim(spec.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(spec.yarn_beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inv = inv / spec.yarn_factor * ramp + inv * (1.0 - ramp)
+    factor = spec.yarn_attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(spec.yarn_factor) + 1.0
+    return inv, factor
+
+
+def rope(x, positions, theta):
     """Rotary embedding, *interleaved* (GPT-J) pair layout.
 
-    x: (..., S, H, Dh) with even Dh; positions: (..., S) int32.
+    x: (..., S, H, Dh) with even Dh; positions: (..., S) int32; theta: the
+    base, or a :class:`Rope` (YaRN where it says so; its attention factor
+    scales cos and sin, so attention downstream is unchanged).
 
     Interleaved pairs (2i, 2i+1) rather than NeoX half-rotation: the
     rotation is then elementwise within any even-sized shard of Dh, so a
@@ -93,12 +128,13 @@ def rope(x, positions, theta: float):
     (same set of 2D rotations, permuted frequency assignment, applied
     consistently to q and k).
     """
+    spec = theta if isinstance(theta, Rope) else Rope(theta)
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs, factor = rope_frequencies(dh, spec)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, half)
-    cos = jnp.cos(ang)[..., :, None, :]  # (..., S, 1, half)
-    sin = jnp.sin(ang)[..., :, None, :]
+    cos = factor * jnp.cos(ang)[..., :, None, :]  # (..., S, 1, half)
+    sin = factor * jnp.sin(ang)[..., :, None, :]
     xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
     x1, x2 = xr[..., 0], xr[..., 1]
     y1 = x1 * cos - x2 * sin
@@ -222,7 +258,7 @@ def init_gqa(key, cfg: ModelConfig, d_model=None, num_heads=None, num_kv=None,
 
 def gqa_attention(p, x, cfg: ModelConfig, *, positions=None, cache=None,
                   window=None, use_rope=True, cross_kv=None, softcap=None,
-                  causal=True, num_valid=None):
+                  causal=True, num_valid=None, rope_spec=None):
     """GQA/MQA/MHA self- or cross-attention with optional KV cache.
 
     cache: None, or dict {k: (B, T, Hkv, Dh), v: ..., idx: ()} — decode mode
@@ -232,17 +268,15 @@ def gqa_attention(p, x, cfg: ModelConfig, *, positions=None, cache=None,
     batches — only honored on the Pallas kernel path (training/prefill),
     where padded rows are grid-skipped instead of merely loss-masked
     (DESIGN.md §14); other paths compute padded rows and rely on the loss
-    mask as before.  Returns (out, new_cache).
+    mask as before.  rope_spec: the layer kind's :class:`Rope` (None =
+    plain rope at ``cfg.rope_theta``).  Returns (out, new_cache).
     """
     b, s, d = x.shape
-    h = p["wq"]["w"].shape[1]
-    dh = cfg.head_dim or (h // max(cfg.num_heads, 1))
-    h_dim = p["wq"]["w"].shape[1]
-    hkv_dim = p["wk"]["w"].shape[1]
     # infer head counts from param shapes (works for reduced configs too)
     dh = cfg.head_dim
-    nh = h_dim // dh
-    nkv = hkv_dim // dh
+    nh = p["wq"]["w"].shape[1] // dh
+    nkv = p["wk"]["w"].shape[1] // dh
+    rope_spec = rope_spec or Rope(cfg.rope_theta)
 
     q = linear(p["wq"], x).reshape(b, s, nh, dh)
     if positions is None:
@@ -251,7 +285,7 @@ def gqa_attention(p, x, cfg: ModelConfig, *, positions=None, cache=None,
     if cross_kv is not None:
         k, v = cross_kv
         if use_rope:
-            q = rope(q, positions, cfg.rope_theta)
+            q = rope(q, positions, rope_spec)
         t = k.shape[1]
         mask = jnp.ones((s, t), dtype=bool)
         out = attention_scores(q, k, v, mask, softcap)
@@ -260,8 +294,8 @@ def gqa_attention(p, x, cfg: ModelConfig, *, positions=None, cache=None,
     k = linear(p["wk"], x).reshape(b, s, nkv, dh)
     v = linear(p["wv"], x).reshape(b, s, nkv, dh)
     if use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, rope_spec)
+        k = rope(k, positions, rope_spec)
 
     if cache is None:
         if cfg.use_pallas and causal and s % 128 == 0:
@@ -469,14 +503,19 @@ def apply_mlp(p, x, cfg: ModelConfig):
 
 
 def init_moe(key, cfg: ModelConfig):
+    """The router over all ``num_experts`` and the experts held here: all
+    of them on the one-hot path, the first ``experts_held`` on the
+    held-expert layer."""
     e, d, f = cfg.num_experts, cfg.d_model, cfg.moe_d_ff or cfg.d_ff
+    held = e if cfg.experts_held is None else cfg.experts_held
     ks = jax.random.split(key, 5)
     std = 1.0 / math.sqrt(d)
     p = {
         "router": {"w": _dense_init(ks[0], (d, e), jnp.float32)},
-        "w_gate": _dense_init(ks[1], (e, d, f), cfg.p_dtype, std),
-        "w_up": _dense_init(ks[2], (e, d, f), cfg.p_dtype, std),
-        "w_down": _dense_init(ks[3], (e, f, d), cfg.p_dtype, 1.0 / math.sqrt(f)),
+        "w_gate": _dense_init(ks[1], (held, d, f), cfg.p_dtype, std),
+        "w_up": _dense_init(ks[2], (held, d, f), cfg.p_dtype, std),
+        "w_down": _dense_init(ks[3], (held, f, d), cfg.p_dtype,
+                              1.0 / math.sqrt(f)),
     }
     if cfg.num_shared_experts:
         fs = f * cfg.num_shared_experts
@@ -542,6 +581,77 @@ def apply_moe(p, x, cfg: ModelConfig):
     if "shared" in p:
         out = out + apply_mlp(p["shared"], x, cfg.with_(mlp="swiglu"))
     return out, aux
+
+
+def apply_held_moe(p, x, cfg: ModelConfig, num_valid=None):
+    """The expert layer of one chip of an expert-parallel deployment: it
+    routes over all ``num_experts`` and computes only the part of the
+    result that its ``experts_held`` (the first ones) give, dropping no
+    token.  With every expert held it is the whole layer.
+
+    Tokens go ``moe_group_size`` at a time through a checkpointed scan, so
+    that the buffers are those of one group: its (token, choice) pairs on
+    held experts, sorted by expert, fill the first rows of a buffer of
+    group x min(top_k, held) rows (the most it can take); SwiGLU runs on
+    them as grouped matmuls (``kernels.grouped_matmul``, whose kernel
+    skips the row tiles past the filled ones), and the products, scaled
+    by their gates, are added back to their tokens.  Rows at or past
+    ``num_valid`` (bucket padding) route nowhere.
+
+    x: (B, S, D).  Returns (out, aux, counters): the Switch balance loss
+    over the router, and ``expert_pairs`` (pairs the held experts
+    computed) and ``expert_pairs_max`` (the largest held expert's load).
+    """
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    b, s, d = x.shape
+    e, k, held = cfg.num_experts, cfg.moe_top_k, cfg.experts_held
+    t = b * s
+    g = min(cfg.moe_group_size, t)
+    pad = (-t) % g
+    rows = g * min(k, held)
+    tokens = jnp.pad(x.reshape(t, d), ((0, pad), (0, 0)))
+    row_of = jnp.arange(t + pad) // s
+    valid = (row_of < (b if num_valid is None else num_valid)) & (
+        jnp.arange(t + pad) < t)
+    kernel = dict(use_kernel=cfg.use_pallas,
+                  interpret=jax.default_backend() == "cpu")
+    w_gate, w_up, w_down = (p[n].astype(x.dtype)
+                            for n in ("w_gate", "w_up", "w_down"))
+
+    @jax.checkpoint
+    def group(xt, vt):
+        # the router in float32 at HIGHEST: a rounding flip near a tie
+        # would change a token's experts
+        logits = jnp.dot(xt.astype(jnp.float32), p["router"]["w"].astype(
+            jnp.float32), precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, k)
+        gates = gates / gates.sum(-1, keepdims=True)
+        expert, gate = experts.reshape(-1), gates.reshape(-1)
+        key = jnp.where((expert < held) & jnp.repeat(vt, k), expert, held)
+        order = jnp.argsort(key, stable=True)[:rows]
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        filled = jnp.arange(rows) < sizes.sum()
+        tok = order // k
+        xin = xt[tok]
+        h = grouped_matmul(xin, w_gate, sizes, **kernel)
+        u = grouped_matmul(xin, w_up, sizes, **kernel)
+        y = grouped_matmul(jax.nn.silu(h) * u, w_down, sizes, **kernel)
+        y = y * jnp.where(filled, gate[order], 0.0).astype(y.dtype)[:, None]
+        out = jnp.zeros_like(xt).at[tok].add(y)
+        vf = vt.astype(jnp.float32)[:, None]
+        chosen = jax.nn.one_hot(experts, e, dtype=jnp.float32).sum(1)
+        return out, sizes, (probs * vf).sum(0), (chosen * vf).sum(0)
+
+    out, sizes, p_sum, c_sum = jax.lax.map(
+        lambda a: group(*a), (tokens.reshape(-1, g, d), valid.reshape(-1, g)))
+    out = out.reshape(-1, d)[:t].reshape(b, s, d)
+    n = jnp.maximum(valid.sum(), 1).astype(jnp.float32)
+    aux = (p_sum.sum(0) / n * c_sum.sum(0) / n).sum() * e
+    load = sizes.sum(0)
+    return out, aux, {"expert_pairs": load.sum(),
+                      "expert_pairs_max": load.max()}
 
 
 # ----------------------------------------------------------- embeddings etc.

@@ -28,10 +28,12 @@ from repro.models.shard_hooks import constrain
 
 
 def block_pattern(cfg: ModelConfig) -> tuple[str, ...]:
-    """Kinds of the repeating block group ('attn' | 'local' | 'rec' | 'ssd')."""
+    """Kinds of the repeating block group ('attn' | 'local' | 'rec' | 'ssd');
+    each attention kind has its own window and rope (``cfg.window_for``,
+    ``cfg.rope_for``)."""
     if cfg.family == "ssm":
         return ("ssd",)
-    if cfg.family == "hybrid":
+    if cfg.family in ("hybrid", "moe"):
         return cfg.block_pattern
     return ("attn",)
 
@@ -70,9 +72,12 @@ def init_block(key, cfg: ModelConfig, kind: str):
 
 def apply_block(p, x, cfg: ModelConfig, kind: str, cache, positions,
                 num_valid=None):
+    """One block.  Returns (x, new_cache, aux, counters): the expert
+    layer's balance loss (0 without one) and what the held-expert layer
+    counts (``L.apply_held_moe``; {} otherwise)."""
     h = L.apply_norm(p["norm1"], x, cfg)
     if kind in ("attn", "local"):
-        window = cfg.local_window if kind == "local" else cfg.window
+        window = cfg.window_for(kind)
         if cfg.attention == "mla":
             # the ragged kernel path is GQA-family only; MLA keeps the
             # loss-mask semantics for padded rows
@@ -81,19 +86,35 @@ def apply_block(p, x, cfg: ModelConfig, kind: str, cache, positions,
         else:
             out, new_cache = L.gqa_attention(
                 p["attn"], h, cfg, positions=positions, cache=cache,
-                window=window, softcap=cfg.attn_softcap, num_valid=num_valid)
+                window=window, softcap=cfg.attn_softcap, num_valid=num_valid,
+                rope_spec=cfg.rope_for(kind))
     elif kind == "rec":
         out, new_cache = R.recurrent_block(p["rec"], h, cfg, cache)
     else:  # ssd
         out, new_cache = S.ssd_block(p["ssd"], h, cfg, cache)
     x = x + out
     aux = jnp.zeros((), jnp.float32)
+    counters = {}
     if "moe" in p:
-        y, aux = L.apply_moe(p["moe"], L.apply_norm(p["norm2"], x, cfg), cfg)
+        h = L.apply_norm(p["norm2"], x, cfg)
+        if cfg.experts_held is None:
+            y, aux = L.apply_moe(p["moe"], h, cfg)
+        else:
+            y, aux, counters = L.apply_held_moe(p["moe"], h, cfg, num_valid)
         x = x + y
     elif "mlp" in p:
         x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["norm2"], x, cfg), cfg)
-    return x, new_cache, aux
+    return x, new_cache, aux, counters
+
+
+def add_counters(a: dict, b: dict) -> dict:
+    """Counters of two blocks together: sums, and the larger of entries
+    ending in ``_max``."""
+    out = dict(a)
+    for k, v in b.items():
+        out[k] = v if k not in out else (
+            jnp.maximum if k.endswith("_max") else jnp.add)(out[k], v)
+    return out
 
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, length: int, dtype):
@@ -164,6 +185,7 @@ def apply_lm(
     caches=None,
     positions=None,
     num_valid=None,
+    counters: bool = False,
 ):
     """Forward pass.
 
@@ -172,7 +194,8 @@ def apply_lm(
     caches: decode-mode cache pytree from init_caches (S must be 1).
     num_valid: optional traced int32 valid-row count for bucket-padded
     batches, threaded to the attention kernels (DESIGN.md §14).
-    Returns (logits (B,S,V) float32, new_caches, aux_loss scalar).
+    Returns (logits (B,S,V) float32, new_caches, aux_loss scalar), and
+    with ``counters`` the blocks' counters (``apply_block``) last.
     """
     pattern = block_pattern(cfg)
     n_groups, n_tail = layer_counts(cfg)
@@ -186,38 +209,39 @@ def apply_lm(
     if positions is None:
         positions = jnp.arange(s)[None, :]
 
+    policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+              if cfg.remat_policy == "dots" else None)
+
+    def block(kind):
+        def run(p, xc, cache):
+            return apply_block(p, xc, cfg, kind, cache, positions, num_valid)
+        # remat: each block is recomputed in the backward from its input,
+        # so that a model of one group (one period) rematerialises too
+        return jax.checkpoint(run, policy=policy) if cfg.remat else run
+
     def group_body(carry, xs):
         xc, aux = carry
-        if caches is None:
-            p_group = xs
-            new_caches = None
-            for i, kind in enumerate(pattern):
-                xc, _, a = apply_block(p_group[f"b{i}"], xc, cfg, kind, None,
-                                       positions, num_valid)
-                xc = constrain(xc, "activations")
-                aux = aux + a
-        else:
-            p_group, cache_group = xs
-            new_caches = {}
-            for i, kind in enumerate(pattern):
-                xc, nc, a = apply_block(p_group[f"b{i}"], xc, cfg, kind,
-                                        cache_group[f"b{i}"], positions,
-                                        num_valid)
-                xc = constrain(xc, "activations")
+        p_group, cache_group = xs if caches is not None else (xs, None)
+        new_caches = {} if caches is not None else None
+        cnt = {}
+        for i, kind in enumerate(pattern):
+            cache_i = None if cache_group is None else cache_group[f"b{i}"]
+            xc, nc, a, c = block(kind)(p_group[f"b{i}"], xc, cache_i)
+            xc = constrain(xc, "activations")
+            if new_caches is not None:
                 new_caches[f"b{i}"] = nc
-                aux = aux + a
-        return (xc, aux), new_caches
+            aux = aux + a
+            cnt = add_counters(cnt, c)
+        return (xc, aux), (new_caches, cnt)
 
-    if cfg.remat:
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat_policy == "dots" else None)
-        body = jax.checkpoint(group_body, policy=policy)
-    else:
-        body = group_body
     xs = params["groups"] if caches is None else (params["groups"],
                                                   caches["groups"])
-    (x, aux), new_group_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs, unroll=cfg.scan_unroll)
+    (x, aux), (new_group_caches, per_group) = jax.lax.scan(
+        group_body, (x, jnp.zeros((), jnp.float32)), xs,
+        unroll=cfg.scan_unroll)
+    # the groups' counters, stacked by the scan, folded as blocks add them
+    cnt = {k: (jnp.max if k.endswith("_max") else jnp.sum)(v, axis=0)
+           for k, v in per_group.items()}
 
     new_caches = None
     if caches is not None:
@@ -226,15 +250,19 @@ def apply_lm(
         new_tail = {}
         for i in range(n_tail):
             cache_i = caches["tail"][f"t{i}"] if caches is not None else None
-            x, nc, a = apply_block(params["tail"][f"t{i}"], x, cfg, pattern[i],
-                                   cache_i, positions, num_valid)
+            x, nc, a, c = apply_block(params["tail"][f"t{i}"], x, cfg,
+                                      pattern[i], cache_i, positions,
+                                      num_valid)
             new_tail[f"t{i}"] = nc
             aux = aux + a
+            cnt = add_counters(cnt, c)
         if caches is not None:
             new_caches["tail"] = new_tail
 
     x = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(params["embed"], params.get("lm_head"), x, cfg)
+    if counters:
+        return logits, new_caches, aux, cnt
     return logits, new_caches, aux
 
 
@@ -242,17 +270,19 @@ def apply_lm(
 
 
 def lm_loss(params, cfg: ModelConfig, tokens, targets, mask,
-            prefix_embeds=None, num_valid=None):
+            prefix_embeds=None, num_valid=None, counters: bool = False):
     """Per-example-weighted cross-entropy.
 
     mask: (B,) example weights (the variable-batching lambda masks) or
     (B, S) token weights. num_valid: optional traced valid-row count for
     bucket-padded batches — must agree with mask (rows >= num_valid carry
     zero weight; see train/mesh.py's suffix-padding contract).
-    Returns (weighted loss sum, weight sum, aux).
+    Returns (weighted loss sum, weight sum, aux), and with ``counters``
+    the blocks' counters (``apply_block``) last.
     """
-    logits, _, aux = apply_lm(params, cfg, tokens, prefix_embeds=prefix_embeds,
-                              num_valid=num_valid)
+    logits, _, aux, cnt = apply_lm(params, cfg, tokens,
+                                   prefix_embeds=prefix_embeds,
+                                   num_valid=num_valid, counters=True)
     nll = L.sharded_xent(logits, targets)
     if mask.ndim == 1:
         tok_w = jnp.broadcast_to(mask[:, None], nll.shape)
@@ -263,4 +293,6 @@ def lm_loss(params, cfg: ModelConfig, tokens, targets, mask,
         tok_w = tok_w.at[:, :p].set(0.0) if hasattr(tok_w, "at") else tok_w
     loss_sum = (nll * tok_w).sum()
     w_sum = tok_w.sum()
+    if counters:
+        return loss_sum, w_sum, aux, cnt
     return loss_sum, w_sum, aux

@@ -198,7 +198,7 @@ class _Dispatch:
     """An in-flight (possibly still executing) worker gradient call."""
 
     worker: int
-    out: tuple                     # (g_mean, loss_sum, w_sum) device arrays
+    out: tuple                     # (g_mean, loss_sum, w_sum, side) on device
     t0: float                      # dispatch timestamp (perf_counter)
     fresh_trace: bool              # this call paid for tracing+compilation
     host_data: object              # pre-transfer batch (for the solo rerun)
@@ -295,6 +295,11 @@ class MeshTrainer(OuterBatchMixin):
         self.timing_reruns = 0     # post-compile re-executions (timing only)
         self.rows_valid = 0        # rows dispatched that the mask counts
         self.rows_padded = 0       # bucket rows past them (mask 0)
+        # what the model counts in its steps (the held-expert layer's
+        # ``expert_pairs``, summed, and ``expert_pairs_max``, the largest
+        # one expert's load in one layer of one step), fetched with the
+        # losses each round already reads
+        self.model_counters: dict[str, int] = {}
         # gradient bytes put onto the full mesh after the workers' slices:
         # each leaf's bytes times the chips it was not on yet
         self.exchange_bytes = 0
@@ -367,22 +372,29 @@ class MeshTrainer(OuterBatchMixin):
 
         def worker_fn(params, batch, mask):
             self.accum_traces += 1  # python side effect: runs at trace time
-            (loss_sum, w_sum, _aux), grads = loss_and_grad(
+            (loss_sum, w_sum, aux), grads = loss_and_grad(
                 params, batch, mask)
+            # side outputs: the model's counters (an aux dict's entries
+            # beside its loss, lm_workload), summed over the shards or the
+            # largest for ``*_max``, and |g_k|^2 for the GNS estimator
+            side = {k: (jax.lax.pmax if k.endswith("_max") else
+                        jax.lax.psum)(v, daxes)
+                    for k, v in (aux.items() if isinstance(aux, dict)
+                                 else ()) if k != "aux_loss"}
             if need_stats:
                 # |g_k|^2 side stat for the GNS estimator rides the
                 # existing psum call (DESIGN.md §15) — no extra pass
-                g_mean, sqn = weighted_psum_with_sqnorm(grads, w_sum, daxes)
-                return (g_mean, jax.lax.psum(loss_sum, daxes),
-                        jax.lax.psum(w_sum, daxes), sqn)
-            g_mean = weighted_psum(grads, w_sum, daxes)
+                g_mean, side["sqnorm"] = weighted_psum_with_sqnorm(
+                    grads, w_sum, daxes)
+            else:
+                g_mean = weighted_psum(grads, w_sum, daxes)
             return (g_mean, jax.lax.psum(loss_sum, daxes),
-                    jax.lax.psum(w_sum, daxes))
+                    jax.lax.psum(w_sum, daxes), side)
 
         sharded = jax.shard_map(
             worker_fn, mesh=mesh_obj,
             in_specs=(P(), P(daxes), P(daxes)),
-            out_specs=(P(), P(), P(), P()) if need_stats else (P(), P(), P()),
+            out_specs=(P(), P(), P(), P()),
             # grads ARE replicated over non-data axes (identical inputs and
             # deterministic compute per slice), but the Pallas kernel's
             # outputs carry no varying-axis type, so the check is off
@@ -625,11 +637,24 @@ class MeshTrainer(OuterBatchMixin):
         with self._span("await", worker=worker):
             jax.block_until_ready(d.out)
             dt = _time.perf_counter() - d.t0
-            loss_sum, w_sum = float(d.out[1]), float(d.out[2])
-            self._last_sqnorm = float(d.out[3]) if len(d.out) > 3 else None
+            loss_sum, w_sum, side = self._fetch_side(d)
+            self._last_sqnorm = side.get("sqnorm")
         if d.fresh_trace:
             dt = self._solo_rerun(d)
         return d.out[0], loss_sum, w_sum, dt * self.dilation[worker]
+
+    def _fetch_side(self, d: _Dispatch) -> tuple[float, float, dict]:
+        """A finished call's loss and weight sums and side outputs, in one
+        fetch; its counters are added to :attr:`model_counters`."""
+        loss_sum, w_sum, side = jax.device_get(d.out[1:])
+        side = {k: float(v) if k == "sqnorm" else int(v)
+                for k, v in side.items()}
+        for k, v in side.items():
+            if k != "sqnorm":
+                have = self.model_counters.get(k, 0)
+                self.model_counters[k] = (max(have, v) if k.endswith("_max")
+                                          else have + v)
+        return float(loss_sum), float(w_sum), side
 
     def _observe_time(self, worker: int, seconds: float) -> float:
         """EWMA filter over measured step times (measurement pipeline; the
@@ -689,17 +714,18 @@ class MeshTrainer(OuterBatchMixin):
             t_issue = _time.perf_counter()
             to = self._full_replicated.device_set
             for d in dispatches:
-                g_mean, loss_sum, w_sum = d.out[:3]
+                g_mean = d.out[0]
+                loss_sum, w_sum, side = self._fetch_side(d)
                 # slice-committed grads must rejoin the full mesh before the
                 # host-side lambda combine
                 grads.append(jax.device_put(g_mean, self._full_replicated))
                 self.exchange_bytes += sum(
                     x.nbytes * len(to - x.sharding.device_set)
                     for x in jax.tree.leaves(g_mean))
-                losses += float(loss_sum)
-                weights += float(w_sum)
-                if len(d.out) > 3:
-                    sqnorms.append(float(d.out[3]))
+                losses += loss_sum
+                weights += w_sum
+                if "sqnorm" in side:
+                    sqnorms.append(side["sqnorm"])
             # the moved gradients are no XLA op: a span on a thread of its
             # own covers them until they are ready on every chip
             self._transfer = self._transfer_pool().submit(

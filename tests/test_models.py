@@ -168,6 +168,7 @@ def test_param_counts_match_citations():
         "llama3-8b": 8e9,
         "gemma-2b": 2.5e9,
         "deepseek-v2-236b": 236e9,
+        "mellum2-12b-a2.5b": 12e9,
     }
     for arch, target in expected.items():
         n = param_count(get_config(arch))

@@ -8,7 +8,10 @@ geometry and the smoke run's shapes (configs/yi_9b.py, chip_smoke.py): a
 4-row bucket of 4096 tokens, 32 query heads and 4 KV heads of 128.  The
 forward plus backward also compiles at the largest bucket of each
 benchmark cell, in float32 with the tiles ``tile_plan`` chooses: Phi-3's
-7 rows of 32 MHA heads of 96, Yi-9B's 6 rows of 32/4 heads of 128.
+7 rows of 32 MHA heads of 96, Yi-9B's 6 rows of 32/4 heads of 128, and
+Mellum2's 4 rows of 32/4 heads of 128 windowed to 1024.  The experts'
+grouped matmul (megablox ``gmm``/``tgmm``) compiles forward and backward
+at Mellum2's widths and token group.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -85,3 +88,50 @@ def test_forward_and_backward_compile(one_chip, dtype, b, h, hkv, d):
         *_inputs(one_chip, dtype, b, h, hkv, d)).compile()
     # the forward, the dq kernel and the dk/dv kernel
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_windowed_forward_and_backward_compile(one_chip):
+    """Mellum2's sliding layers: the band of tiles a 1024 window keeps."""
+    def grads(q, k, v, nv):
+        def loss(q_, k_, v_):
+            out = attention(q_, k_, v_, num_valid=nv, window=1024)
+            return out.sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(
+        *_inputs(one_chip, jnp.float32, 4, 32, 4, 128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_grouped_matmul_compiles(one_chip, monkeypatch):
+    """Mellum2's held-expert layer at its widths over one 4096-token group
+    (8 held of 64 experts of 896, d 2304), forward and backward, with the
+    kernel on (the layer asks ``jax.default_backend()`` whether to
+    interpret it, so the test answers "tpu"): the forward, recomputed and
+    input-gradient products are ``gmm`` calls, the weight gradients
+    ``tgmm``, the stems the benchmark's expert readers time."""
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("mellum2-12b-a2.5b").with_(experts_held=8,
+                                                use_pallas=True)
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+
+    def struct(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    params = {"router": {"w": struct((d, e))},
+              "w_gate": struct((8, d, f)), "w_up": struct((8, d, f)),
+              "w_down": struct((8, f, d))}
+
+    def grads(p, x):
+        return jax.value_and_grad(
+            lambda p_, x_: L.apply_held_moe(p_, x_, cfg)[0].sum(),
+            argnums=(0, 1))(p, x)
+
+    compiled = jax.jit(grads).lower(params, struct((1, 4096, d))).compile()
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert sorted(calls) == ["gmm"] * 9 + ["tgmm"] * 3
