@@ -11,6 +11,7 @@ All attention variants support two modes:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -583,75 +584,165 @@ def apply_moe(p, x, cfg: ModelConfig):
     return out, aux
 
 
+def held_rungs(group: int, top_k: int, held: int, num_experts: int):
+    """The row counts a token group's pair buffer may take, smallest
+    first: twice, four times, ... the pairs a group fills on average
+    (group x top_k x held / num_experts), each rounded up to 8 rows and,
+    past one kernel row tile, to whole tiles, then the most a group can
+    fill, group x min(top_k, held).  Where twice the average is already
+    that most, the one rung is it."""
+    from repro.kernels.grouped_matmul.ops import MAX_TM
+
+    rows = group * min(top_k, held)
+    rungs, m = [], 2
+    while True:
+        r = -(-m * group * top_k * held // num_experts)
+        r = -(-r // 8) * 8
+        if r > MAX_TM:
+            r = -(-r // MAX_TM) * MAX_TM
+        if r >= rows:
+            return tuple(rungs) + (rows,)
+        if not rungs or r > rungs[-1]:
+            rungs.append(r)
+        m *= 2
+
+
+def _pairs_at(rows, kernel, ws, xt, tok, gate, sizes):
+    """One group's result from the held experts, its pairs in a buffer of
+    ``rows`` rows: gathered, SwiGLU as three grouped matmuls, scaled by
+    their gates (zero past the filled rows) and added to their tokens."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    use_kernel, interpret = kernel
+    w_gate, w_up, w_down = ws
+    tok, gate = tok[:rows], gate[:rows]
+    xin = xt[tok]
+    mm = dict(use_kernel=use_kernel, interpret=interpret)
+    h = grouped_matmul(xin, w_gate, sizes, **mm)
+    u = grouped_matmul(xin, w_up, sizes, **mm)
+    y = grouped_matmul(jax.nn.silu(h) * u, w_down, sizes, **mm)
+    return jnp.zeros_like(xt).at[tok].add(y * gate.astype(y.dtype)[:, None])
+
+
+def _branches(rungs, kernel, fn):
+    return [lambda *a, r=r: fn(r, kernel, *a) for r in rungs]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_pairs(rungs, kernel, rung, ws, xt, tok, gate, sizes):
+    """``_pairs_at`` at ``rungs[rung]`` rows.  Its gradient is taken one
+    rung at a time: the backward recomputes the taken rung's forward and
+    applies its VJP, where JAX's own linearisation of the ``switch``
+    would keep every rung's residuals, the unused ones zero-filled."""
+    return jax.lax.switch(rung, _branches(rungs, kernel, _pairs_at),
+                          ws, xt, tok, gate, sizes)
+
+
+def _held_pairs_fwd(rungs, kernel, rung, ws, xt, tok, gate, sizes):
+    return (_held_pairs(rungs, kernel, rung, ws, xt, tok, gate, sizes),
+            (rung, ws, xt, tok, gate, sizes))
+
+
+def _pairs_vjp(rows, kernel, ws, xt, tok, gate, sizes, d_out):
+    # checkpointed, so that the kernels keep their op names (``gmm``,
+    # ``tgmm``) in a device trace: a plain ``jax.vjp`` prefixes them with
+    # its transforms; the primal it computes first goes unused and is cut
+    _, vjp = jax.vjp(jax.checkpoint(
+        lambda w, x, g: _pairs_at(rows, kernel, w, x, tok, g, sizes)),
+        ws, xt, gate)
+    return vjp(d_out)
+
+
+def _held_pairs_bwd(rungs, kernel, res, d_out):
+    rung, ws, xt, tok, gate, sizes = res
+    d_ws, d_xt, d_gate = jax.lax.switch(
+        rung, _branches(rungs, kernel, _pairs_vjp),
+        ws, xt, tok, gate, sizes, d_out)
+    return None, d_ws, d_xt, None, d_gate, None
+
+
+_held_pairs.defvjp(_held_pairs_fwd, _held_pairs_bwd)
+
+
+# jitted, so that every layer and bucket of the same shapes shares one
+# trace and its derivatives: traced once a layer, the ladder's branches
+# made each worker step's set-up seconds longer
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _route_group(k, held, rungs, kernel, router, ws, xt, vt):
+    """One group of tokens through the held-expert layer: its result, the
+    pairs each held expert took, the router's probabilities and choices
+    summed over the valid tokens, the pairs filled and whether they took
+    the largest rung."""
+    # the router in float32 at HIGHEST: a rounding flip near a tie would
+    # change a token's experts
+    logits = jnp.dot(xt.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, k)
+    gates = gates / gates.sum(-1, keepdims=True)
+    expert, gate = experts.reshape(-1), gates.reshape(-1)
+    key = jnp.where((expert < held) & jnp.repeat(vt, k), expert, held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    filled = sizes.sum()
+    rung = (filled > jnp.array(rungs[:-1], jnp.int32)).sum()
+    gate = jnp.where(jnp.arange(gate.shape[0]) < filled, gate[order], 0.0)
+    out = _held_pairs(rungs, kernel, rung, ws, xt, order // k, gate, sizes)
+    vf = vt.astype(jnp.float32)[:, None]
+    chosen = jax.nn.one_hot(experts, router.shape[1], dtype=jnp.float32
+                            ).sum(1)
+    return (out, sizes, (probs * vf).sum(0), (chosen * vf).sum(0), filled,
+            rung == len(rungs) - 1)
+
+
 def apply_held_moe(p, x, cfg: ModelConfig, num_valid=None):
     """The expert layer of one chip of an expert-parallel deployment: it
     routes over all ``num_experts`` and computes only the part of the
     result that its ``experts_held`` (the first ones) give, dropping no
     token.  With every expert held it is the whole layer.
 
-    Tokens go ``moe_group_size`` at a time through a checkpointed scan, so
-    that the buffers are those of one group: its (token, choice) pairs on
-    held experts, sorted by expert, fill the first rows of a buffer of
-    group x min(top_k, held) rows (the most it can take); SwiGLU runs on
-    them as grouped matmuls (``kernels.grouped_matmul``, whose kernel
-    skips the row tiles past the filled ones), and the products, scaled
-    by their gates, are added back to their tokens.  Rows at or past
-    ``num_valid`` (bucket padding) route nowhere.
+    Tokens go ``moe_group_size`` at a time through a scan.  A group's
+    (token, choice) pairs on held experts, sorted by expert, fill the
+    first rows of a buffer whose size is the smallest of ``held_rungs``
+    that holds them (the largest is the most a group can fill); SwiGLU
+    runs on them as grouped matmuls (``kernels.grouped_matmul``), and the
+    products, scaled by their gates, are added back to their tokens.
+    A group is checkpointed: the backward keeps its input alone, routes
+    it again and recomputes the rung it took (``_held_pairs``).  Rows at
+    or past ``num_valid`` (bucket padding) route nowhere.
 
     x: (B, S, D).  Returns (out, aux, counters): the Switch balance loss
-    over the router, and ``expert_pairs`` (pairs the held experts
-    computed) and ``expert_pairs_max`` (the largest held expert's load).
+    over the router, ``expert_pairs`` (pairs the held experts computed),
+    ``expert_pairs_max`` (the largest held expert's load),
+    ``expert_rows_max`` (the most pairs a group filled) and
+    ``expert_groups_full`` (groups that took the largest rung; with one
+    rung, every group).
     """
-    from repro.kernels.grouped_matmul import grouped_matmul
-
     b, s, d = x.shape
     e, k, held = cfg.num_experts, cfg.moe_top_k, cfg.experts_held
     t = b * s
     g = min(cfg.moe_group_size, t)
     pad = (-t) % g
-    rows = g * min(k, held)
+    rungs = held_rungs(g, k, held, e)
     tokens = jnp.pad(x.reshape(t, d), ((0, pad), (0, 0)))
     row_of = jnp.arange(t + pad) // s
     valid = (row_of < (b if num_valid is None else num_valid)) & (
         jnp.arange(t + pad) < t)
-    kernel = dict(use_kernel=cfg.use_pallas,
-                  interpret=jax.default_backend() == "cpu")
-    w_gate, w_up, w_down = (p[n].astype(x.dtype)
-                            for n in ("w_gate", "w_up", "w_down"))
+    kernel = (cfg.use_pallas, jax.default_backend() == "cpu")
+    ws = tuple(p[n].astype(x.dtype) for n in ("w_gate", "w_up", "w_down"))
 
-    @jax.checkpoint
-    def group(xt, vt):
-        # the router in float32 at HIGHEST: a rounding flip near a tie
-        # would change a token's experts
-        logits = jnp.dot(xt.astype(jnp.float32), p["router"]["w"].astype(
-            jnp.float32), precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = jax.lax.top_k(probs, k)
-        gates = gates / gates.sum(-1, keepdims=True)
-        expert, gate = experts.reshape(-1), gates.reshape(-1)
-        key = jnp.where((expert < held) & jnp.repeat(vt, k), expert, held)
-        order = jnp.argsort(key, stable=True)[:rows]
-        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        filled = jnp.arange(rows) < sizes.sum()
-        tok = order // k
-        xin = xt[tok]
-        h = grouped_matmul(xin, w_gate, sizes, **kernel)
-        u = grouped_matmul(xin, w_up, sizes, **kernel)
-        y = grouped_matmul(jax.nn.silu(h) * u, w_down, sizes, **kernel)
-        y = y * jnp.where(filled, gate[order], 0.0).astype(y.dtype)[:, None]
-        out = jnp.zeros_like(xt).at[tok].add(y)
-        vf = vt.astype(jnp.float32)[:, None]
-        chosen = jax.nn.one_hot(experts, e, dtype=jnp.float32).sum(1)
-        return out, sizes, (probs * vf).sum(0), (chosen * vf).sum(0)
-
-    out, sizes, p_sum, c_sum = jax.lax.map(
+    group = jax.checkpoint(functools.partial(
+        _route_group, k, held, rungs, kernel, p["router"]["w"], ws))
+    out, sizes, p_sum, c_sum, filled, full = jax.lax.map(
         lambda a: group(*a), (tokens.reshape(-1, g, d), valid.reshape(-1, g)))
     out = out.reshape(-1, d)[:t].reshape(b, s, d)
     n = jnp.maximum(valid.sum(), 1).astype(jnp.float32)
     aux = (p_sum.sum(0) / n * c_sum.sum(0) / n).sum() * e
     load = sizes.sum(0)
     return out, aux, {"expert_pairs": load.sum(),
-                      "expert_pairs_max": load.max()}
+                      "expert_pairs_max": load.max(),
+                      "expert_rows_max": filled.max(),
+                      "expert_groups_full": full.sum(dtype=jnp.int32)}
 
 
 # ----------------------------------------------------------- embeddings etc.

@@ -103,18 +103,19 @@ def test_windowed_forward_and_backward_compile(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
-def test_grouped_matmul_compiles(one_chip, monkeypatch):
+def _held_layer(one_chip, monkeypatch, held=8, rungs=None):
     """Mellum2's held-expert layer at its widths over one 4096-token group
-    (8 held of 64 experts of 896, d 2304), forward and backward, with the
-    kernel on (the layer asks ``jax.default_backend()`` whether to
-    interpret it, so the test answers "tpu"): the forward, recomputed and
-    input-gradient products are ``gmm`` calls, the weight gradients
-    ``tgmm``, the stems the benchmark's expert readers time."""
+    (``held`` of 64 experts of 896, d 2304), forward and backward,
+    compiled with the kernel on (the layer asks ``jax.default_backend()``
+    whether to interpret it, so the answer is "tpu"); ``rungs`` in place
+    of the layer's ladder."""
     from repro.configs import get_config
     from repro.models import layers as L
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config("mellum2-12b-a2.5b").with_(experts_held=8,
+    if rungs is not None:
+        monkeypatch.setattr(L, "held_rungs", lambda *a: rungs)
+    cfg = get_config("mellum2-12b-a2.5b").with_(experts_held=held,
                                                 use_pallas=True)
     d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
 
@@ -122,16 +123,52 @@ def test_grouped_matmul_compiles(one_chip, monkeypatch):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     params = {"router": {"w": struct((d, e))},
-              "w_gate": struct((8, d, f)), "w_up": struct((8, d, f)),
-              "w_down": struct((8, f, d))}
+              "w_gate": struct((held, d, f)), "w_up": struct((held, d, f)),
+              "w_down": struct((held, f, d))}
 
     def grads(p, x):
         return jax.value_and_grad(
             lambda p_, x_: L.apply_held_moe(p_, x_, cfg)[0].sum(),
             argnums=(0, 1))(p, x)
 
-    compiled = jax.jit(grads).lower(params, struct((1, 4096, d))).compile()
-    calls = [line.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
-             for line in compiled.as_text().splitlines()
-             if "tpu_custom_call" in line and " = " in line]
-    assert sorted(calls) == ["gmm"] * 9 + ["tgmm"] * 3
+    return jax.jit(grads).lower(params, struct((1, 4096, d))).compile()
+
+
+def _kernel_calls(compiled):
+    return sorted(line.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
+                  for line in compiled.as_text().splitlines()
+                  if "tpu_custom_call" in line and " = " in line)
+
+
+def test_grouped_matmul_compiles(one_chip, monkeypatch):
+    """With 8 of 64 experts held a group's pairs take one of three buffer
+    sizes (8,192, 16,384 or 32,768 rows), each compiled: per size the
+    forward, recomputed and input-gradient products are ``gmm`` calls,
+    the weight gradients ``tgmm``, the stems the benchmark's expert
+    readers time."""
+    calls = _kernel_calls(_held_layer(one_chip, monkeypatch))
+    assert calls == ["gmm"] * 9 * 3 + ["tgmm"] * 3 * 3
+
+
+def test_grouped_matmul_one_rung_with_every_expert_held(one_chip,
+                                                        monkeypatch):
+    """With all 64 experts held the layer has one buffer size, the most
+    a group can fill: one set of kernels and no conditional."""
+    compiled = _held_layer(one_chip, monkeypatch, held=64)
+    assert _kernel_calls(compiled) == ["gmm"] * 9 + ["tgmm"] * 3
+    assert " conditional(" not in compiled.as_text()
+
+
+def test_compact_rung_needs_fewer_temporaries(one_chip, monkeypatch):
+    """By the compiler's count: the smallest buffer (8,192 rows) needs
+    fewer temporary bytes than the 32,768-row one every group used to
+    take, and the whole ladder no more than those two together, which a
+    backward keeping every size's residuals (JAX's own linearisation of
+    the ``switch``, unused ones zero-filled) would exceed."""
+    def temps(rungs):
+        return _held_layer(one_chip, monkeypatch, rungs=rungs
+                           ).memory_analysis().temp_size_in_bytes
+
+    small, full = temps((8192,)), temps((32768,))
+    assert small < full
+    assert temps(None) < small + full
